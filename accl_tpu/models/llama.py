@@ -25,7 +25,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import shard_map as _shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -269,7 +268,7 @@ class Llama:
                     spec = P(dp_ax, None, ax, None)
                     f = functools.partial(ring_attention, axis_name=ax,
                                           causal=True)
-                attn = _shard_map(f, mesh=mesh,
+                attn = jax.shard_map(f, mesh=mesh,
                                      in_specs=(spec, spec, spec),
                                      out_specs=spec,
                                      check_vma=False)(qt, kt, vt)
@@ -397,26 +396,27 @@ class Llama:
 
     # -- inference: KV-cache decode ----------------------------------------
     def init_kv_cache(self, batch: int, max_len: int, dtype=None) -> dict:
-        """Preallocated static-shape KV cache: (L, B, max_len, n_kv, hd)
-        per tensor + a scalar fill position. Static shapes keep every
-        decode step a single compiled program (no growing arrays)."""
+        """Preallocated static-shape KV cache: (L, B, n_kv, max_len, hd)
+        per tensor + a scalar fill position. Head-major, so the decode
+        kernel's (block, hd) tile per kv head is TPU-tileable; static
+        shapes keep every decode step one compiled program."""
         c = self.config
         dt = dtype or c.dtype
-        shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
+        shape = (c.n_layers, batch, c.n_kv_heads, max_len, c.head_dim)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
                 "pos": jnp.zeros((), jnp.int32)}
 
     def _layer_cached(self, x, layer_params, kc, vc, pos,
                       shard_ctx=None):
         """One decoder layer over cached context: x holds S_new tokens at
-        absolute positions pos..pos+S_new-1; kc/vc are (B, max_len, nkv, hd)
+        absolute positions pos..pos+S_new-1; kc/vc are (B, nkv, max_len, hd)
         and are updated in place (dynamic_update_slice). Returns
         (x, kc, vc)."""
         c = self.config
         p = layer_params
         hd, nh, nkv = c.head_dim, c.n_heads, c.n_kv_heads
         B, S, D = x.shape
-        max_len = kc.shape[1]
+        max_len = kc.shape[2]
 
         h = _rms_norm(x, p["attn_norm"].astype(x.dtype), c.norm_eps)
         positions = pos + jnp.arange(S)
@@ -425,10 +425,10 @@ class Llama:
         v = (h @ p["wv"].astype(x.dtype)).reshape(B, S, nkv, hd)
         q = _rope(q, positions, c.rope_theta)
         k = _rope(k, positions, c.rope_theta)
-        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
-                                          (0, pos, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
-                                          (0, pos, 0, 0))
+        kc = jax.lax.dynamic_update_slice(
+            kc, k.transpose(0, 2, 1, 3).astype(kc.dtype), (0, 0, pos, 0))
+        vc = jax.lax.dynamic_update_slice(
+            vc, v.transpose(0, 2, 1, 3).astype(vc.dtype), (0, 0, pos, 0))
 
         if self.config.attention == "flash":
             # fused decode kernel over the cache's native layout: cache
@@ -441,12 +441,12 @@ class Llama:
                 # tp shard decodes its own head group with the fused
                 # kernel (no cache gather, no repeated-KV copy)
                 mesh, dp_ax, tp_ax = shard_ctx
-                attn = _shard_map(
+                attn = jax.shard_map(
                     flash_decode,
                     mesh=mesh,
                     in_specs=(P(dp_ax, tp_ax, None, None),
-                              P(dp_ax, None, tp_ax, None),
-                              P(dp_ax, None, tp_ax, None), P()),
+                              P(dp_ax, tp_ax, None, None),
+                              P(dp_ax, tp_ax, None, None), P()),
                     out_specs=P(dp_ax, tp_ax, None, None),
                     check_vma=False)(qt, kc, vc, pos + S)
             else:
@@ -458,17 +458,17 @@ class Llama:
             # fold the per-kv-head query group into the einsum instead
             rep = nh // nkv
             qg = q.reshape(B, S, nkv, rep, hd)        # (B, S, nkv, rep, hd)
-            kt = kc.astype(x.dtype)                   # (B, max, nkv, hd)
+            kt = kc.astype(x.dtype)                   # (B, nkv, max, hd)
             vt = vc.astype(x.dtype)
             scores = jnp.einsum(
-                "bskrd,btkd->bkrst", qg, kt,
+                "bskrd,bktd->bkrst", qg, kt,
                 preferred_element_type=jnp.float32) * (hd ** -0.5)
             kpos = jnp.arange(max_len)
             mask = kpos[None, :] <= positions[:, None]  # (S, max) causal
             scores = jnp.where(mask[None, None, None], scores,
                                jnp.finfo(jnp.float32).min)
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            attn = jnp.einsum("bkrst,btkd->bskrd", probs, vt)
+            attn = jnp.einsum("bkrst,bktd->bskrd", probs, vt)
             attn = attn.reshape(B, S, nh * hd)
         x = x + attn @ p["wo"].astype(x.dtype)
 

@@ -20,8 +20,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import shard_map as _shard_map
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -128,7 +126,7 @@ class MoELayer:
         """Inside shard_map: tokens sharded over ``axis_name`` (T_local, d);
         expert params carry only this rank's E/W experts."""
         c = self.config
-        W = _axis_size(axis_name)
+        W = jax.lax.axis_size(axis_name)
         E = c.n_experts
         assert E % W == 0, f"{E} experts not divisible by ep={W}"
         E_loc = E // W
@@ -158,7 +156,7 @@ def _ep_program(cfg: MoEConfig, mesh: Mesh, axis_name: str, capacity: int):
     pspec = lyr.param_specs(axis_name)
     xspec = P(axis_name, None)
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(pspec, xspec), out_specs=(xspec, P()))
     def f(params, x):
         out, aux = lyr.apply_ep(params, x, axis_name, capacity)

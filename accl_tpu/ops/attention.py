@@ -20,11 +20,12 @@ Three kernels:
   Q innermost), one accumulates dQ (grid over Q blocks, KV innermost).
   Wired via ``jax.custom_vjp`` so models can train through it.
 * ``flash_decode`` — KV-cache decode (q_len << kv_len). Operates on the
-  cache's native (B, T, H_kv, D) layout with the fill length as a
-  scalar-prefetch operand: blocks past the fill are neither fetched
-  (index map clamps -> the pipeline skips the repeat DMA) nor computed
-  (``pl.when``), so a step on a part-full cache costs what the FILLED
-  prefix costs, not what max_len costs.
+  cache's native head-major (B, H_kv, T, D) layout, whose (block_k, D)
+  tile per kv head meets the TPU's (8, 128) tiling rule for any H_kv,
+  with the fill length as a scalar-prefetch operand: blocks past the
+  fill are neither fetched (index map clamps -> the pipeline skips the
+  repeat DMA) nor computed (``pl.when``), so a step on a part-full
+  cache costs what the FILLED prefix costs, not what max_len costs.
 
 The reference has no attention (it is a collectives library); these
 kernels exist because the rebuild's flagship models and ring attention
@@ -41,20 +42,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.compat import tpu_compiler_params as _tpu_compiler_params
+from ..utils.platform import pallas_interpret as _interpret
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 _LANES = 128  # min lane tile; lse/delta ride in lane-broadcast layout
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _compiler_params(ndims: int):
     """Last grid dim is the streamed (revisiting) one; the rest are
     embarrassingly parallel."""
-    return _tpu_compiler_params()(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel",) * (ndims - 1) + ("arbitrary",))
 
 
@@ -99,6 +96,21 @@ def _tile_lanes(x: jax.Array, width: int) -> jax.Array:
     return jnp.broadcast_to(_row_vals(x), (x.shape[0], width))
 
 
+def _precision(dtype):
+    """MXU precision of the kernels' f32 dots: f32 inputs get f32
+    products (HIGHEST, Mosaic's fp32 contract), narrower inputs keep the
+    single bf16-pass default their dtype already asks for."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _dot(a, b, dims, prec):
+    """f32-accumulated a . b contracting a's dim dims[0] with b's
+    dims[1]."""
+    return jax.lax.dot_general(a, b, (((dims[0],), (dims[1],)), ((), ())),
+                               precision=prec,
+                               preferred_element_type=jnp.float32)
+
+
 def _sds_for(x: jax.Array):
     """ShapeDtypeStruct factory carrying x's varying-mesh-axes set when
     inside shard_map (check_vma requires it explicit on pallas_call
@@ -127,13 +139,11 @@ def _block_mask(*, causal, q_off, k_off, bq, bk, skv, sq=None):
     return mask
 
 
-def _masked_scores(q, k, *, sm_scale, causal, q_off, k_off,
-                   skv) -> jax.Array:
+def _masked_scores(q, k, *, sm_scale, causal, q_off, k_off, skv,
+                   prec) -> jax.Array:
     """scale * q @ k^T with the shared block mask applied as -inf."""
     bq, bk = q.shape[0], k.shape[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
+    s = _dot(q, k, (1, 1), prec) * sm_scale
     mask = _block_mask(causal=causal, q_off=q_off, k_off=k_off,
                        bq=bq, bk=bk, skv=skv)
     return jnp.where(mask, s, _NEG_INF)
@@ -144,6 +154,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
                 skv: int):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
+    prec = _precision(q_ref.dtype)
     nk = pl.num_programs(2)
 
     @pl.when(kj == 0)
@@ -168,7 +179,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         d = q.shape[-1]
         s = _masked_scores(q, k, sm_scale=sm_scale, causal=causal,
                            q_off=qi * block_q, k_off=kj * block_k,
-                           skv=skv)
+                           skv=skv, prec=prec)
 
         m_prev = _row_vals(m_sc[...])             # (block_q, 1)
         l_prev = _row_vals(l_sc[...])
@@ -177,9 +188,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         p = jnp.exp(s - _tile_lanes(_bcast_lanes(m_new), block_k))
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_sc[...] = (acc_sc[...] * _tile_lanes(_bcast_lanes(alpha), d)
-                       + jax.lax.dot_general(
-                           p, v, (((1,), (0,)), ((), ())),
-                           preferred_element_type=jnp.float32))
+                       + _dot(p, v, (1, 0), prec))
         m_sc[...] = _bcast_lanes(m_new)
         l_sc[...] = _bcast_lanes(l_new)
 
@@ -200,16 +209,16 @@ def _fwd_kernel_single(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     with no scratch round trips or online-update bookkeeping — the
     short-sequence regime where that machinery is pure overhead."""
     qi = pl.program_id(1)
+    prec = _precision(q_ref.dtype)
     q = q_ref[0].astype(jnp.float32)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     s = _masked_scores(q, k, sm_scale=sm_scale, causal=causal,
-                       q_off=qi * block_q, k_off=0, skv=skv)
+                       q_off=qi * block_q, k_off=0, skv=skv, prec=prec)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - _tile_lanes(_bcast_lanes(m), block_k))
     l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    o = _dot(p, v, (1, 0), prec)
     o_ref[0] = (o / l).astype(o_ref.dtype)
     lse_ref[0] = _bcast_lanes(m + jnp.log(l))
 
@@ -277,7 +286,7 @@ def _fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
             ),
             # no scratch, no revisiting: both grid dims are
             # embarrassingly parallel (megacore-partitionable)
-            compiler_params=_tpu_compiler_params()(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=_interpret(),
         )(qp, kp, vp)
@@ -319,12 +328,10 @@ def _fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
 # ---------------------------------------------------------------------------
 
 def _recompute_p(q, k, lse_tile, *, sm_scale, causal, block_q, block_k,
-                 qi, kj, sq, skv):
+                 qi, kj, sq, skv, prec):
     """Shared bwd step: rebuild the (block_q, block_k) probability block
     from saved lse, with padding + causal masking applied."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
+    s = _dot(q, k, (1, 1), prec) * sm_scale
     p = jnp.exp(s - _tile_lanes(lse_tile, block_k))
     mask = _block_mask(causal=causal, q_off=qi * block_q,
                        k_off=kj * block_k, bq=block_q, bk=block_k,
@@ -338,6 +345,7 @@ def _bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
                     sq: int, skv: int):
     kj = pl.program_id(1)
     qi = pl.program_id(2)
+    prec = _precision(q_ref.dtype)
     nq = pl.num_programs(2)
 
     @pl.when(qi == 0)
@@ -359,18 +367,13 @@ def _bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
         v = v_ref[0].astype(jnp.float32)
         p = _recompute_p(q, k, lse_ref[0], sm_scale=sm_scale,
                             causal=causal, block_q=block_q,
-                            block_k=block_k, qi=qi, kj=kj, sq=sq, skv=skv)
+                            block_k=block_k, qi=qi, kj=kj, sq=sq, skv=skv,
+                            prec=prec)
         # dv += p^T do ; contraction over the q rows
-        dv_sc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv_sc[...] += _dot(p, do, (0, 0), prec)
+        dp = _dot(do, v, (1, 1), prec)
         ds = p * (dp - _tile_lanes(delta_ref[0], block_k))
-        dk_sc[...] += sm_scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dk_sc[...] += sm_scale * _dot(ds, q, (0, 0), prec)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -383,6 +386,7 @@ def _bwd_dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
                    block_q: int, block_k: int, sq: int, skv: int):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
+    prec = _precision(q_ref.dtype)
     nk = pl.num_programs(2)
 
     @pl.when(kj == 0)
@@ -402,14 +406,11 @@ def _bwd_dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
         v = v_ref[0].astype(jnp.float32)
         p = _recompute_p(q, k, lse_ref[0], sm_scale=sm_scale,
                             causal=causal, block_q=block_q,
-                            block_k=block_k, qi=qi, kj=kj, sq=sq, skv=skv)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+                            block_k=block_k, qi=qi, kj=kj, sq=sq, skv=skv,
+                            prec=prec)
+        dp = _dot(do, v, (1, 1), prec)
         ds = p * (dp - _tile_lanes(delta_ref[0], block_k))
-        dq_sc[...] += sm_scale * jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_sc[...] += sm_scale * _dot(ds, k, (1, 0), prec)
 
     @pl.when(kj == nk - 1)
     def _finish():
@@ -571,8 +572,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc,
                    acc_sc, *, sm_scale: float, block_k: int, rows: int,
                    s_new: int):
-    kj = pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    prec = _precision(q_ref.dtype)
+    nk = pl.num_programs(3)
     kvlen = kvlen_ref[0]
 
     @pl.when(kj == 0)
@@ -586,8 +589,8 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc,
     @pl.when(kj < needed)
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)       # (rows, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (block_k, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)       # (block_k, d)
+        v = v_ref[0, 0].astype(jnp.float32)
         d = q.shape[-1]
         # T need not divide block_k: the last block's tail rows are
         # out-of-bounds reads (undefined — NaN in interpret mode) and
@@ -596,13 +599,12 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc,
         kv_valid = (kj * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_k, d), 0)) < kvlen
         v = jnp.where(kv_valid, v, 0.0)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
+        s = _dot(q, k, (1, 1), prec) * sm_scale
         # row r of q holds (group g, new-token i) with i = r % s_new at
         # absolute position kvlen - s_new + i; padded rows are garbage
         # and sliced off outside
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0)
+        row = qi * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 0)
         q_pos = kvlen - s_new + row % s_new
         k_pos = kj * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_k), 1)
@@ -618,9 +620,7 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc,
         l_sc[...] = _bcast_lanes(
             l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True))
         acc_sc[...] = (acc_sc[...] * _tile_lanes(_bcast_lanes(alpha), d)
-                       + jax.lax.dot_general(
-                           p, v, (((1,), (0,)), ((), ())),
-                           preferred_element_type=jnp.float32))
+                       + _dot(p, v, (1, 0), prec))
         m_sc[...] = _bcast_lanes(m_new)
 
     @pl.when(kj == nk - 1)
@@ -637,7 +637,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     q: (B, H, S_new, D) — the S_new newest tokens' queries, whose
     absolute positions are ``kv_len - S_new .. kv_len - 1``.
-    k_cache/v_cache: (B, T, H_kv, D) in the cache's NATIVE layout (no
+    k_cache/v_cache: (B, H_kv, T, D) in the cache's NATIVE layout (no
     transpose copies), filled through ``kv_len`` (a traced int32 scalar —
     the same compiled program serves every step).  Causal within the new
     tokens. Returns (B, H, S_new, D).
@@ -647,7 +647,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     DMA elision) nor computed (``pl.when``), so the cost of a step
     scales with the filled prefix, not with T."""
     B, H, S_new, D = q.shape
-    T, Hkv = k_cache.shape[1], k_cache.shape[2]
+    Hkv, T = k_cache.shape[1], k_cache.shape[2]
     if H % Hkv:
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
     group = H // Hkv
@@ -657,41 +657,44 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     nk = pl.cdiv(T, block_k)
 
     # (B, H, S_new, D) -> (B, Hkv, group*S_new, D): rows of one kv head's
-    # q group share that head's streamed K/V blocks
+    # q group share that head's streamed K/V blocks. A chunked prefill's
+    # rows are tiled in block_q row blocks so VMEM holds one (block_q,
+    # block_k) score tile whatever S_new is
     rows = group * S_new
-    rows_p = max(8, rows + (-rows) % 8)
-    qr = q.reshape(B, Hkv, rows, D)
-    qr = _pad_to(qr, 2, rows_p)
+    block_q = min(512, max(8, rows + (-rows) % 8))
+    qr = _pad_to(q.reshape(B, Hkv, rows, D), 2, block_q)
+    rows_p = qr.shape[2]
 
     kvlen = jnp.asarray(kv_len, jnp.int32).reshape(1)
 
-    def kv_index(b, h, kj, kvlen_ref):
+    def q_index(b, h, qi, kj, kvlen_ref):
+        return (b, h, qi, 0)
+
+    def kv_index(b, h, qi, kj, kvlen_ref):
         last = jnp.maximum(pl.cdiv(kvlen_ref[0], block_k) - 1, 0)
-        return (b, jnp.minimum(kj, last), h, 0)
+        return (b, h, jnp.minimum(kj, last), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hkv, nk),
+        grid=(B, Hkv, rows_p // block_q, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, rows_p, D),
-                         lambda b, h, kj, kvlen_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D), kv_index),
-            pl.BlockSpec((1, block_k, 1, D), kv_index),
+            pl.BlockSpec((1, 1, block_q, D), q_index),
+            pl.BlockSpec((1, 1, block_k, D), kv_index),
+            pl.BlockSpec((1, 1, block_k, D), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, rows_p, D),
-                               lambda b, h, kj, kvlen_ref: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, block_q, D), q_index),
         scratch_shapes=[
-            pltpu.VMEM((rows_p, _LANES), jnp.float32),
-            pltpu.VMEM((rows_p, _LANES), jnp.float32),
-            pltpu.VMEM((rows_p, D), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, sm_scale=sm_scale,
-                          block_k=block_k, rows=rows_p, s_new=S_new),
+                          block_k=block_k, rows=block_q, s_new=S_new),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_compiler_params(3),
+        compiler_params=_compiler_params(4),
         interpret=_interpret(),
     )(kvlen, qr, k_cache, v_cache)
     return out[:, :, :rows].reshape(B, H, S_new, D)

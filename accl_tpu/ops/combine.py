@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.platform import pallas_interpret as _interpret
+
 from ..constants import ReduceFunc
 
 # lane count is fixed at 128 on TPU; 8 sublanes x 128 lanes is the fp32 tile
@@ -35,10 +37,6 @@ _FUNCS = {
     ReduceFunc.MIN: jnp.minimum,
     ReduceFunc.PROD: jnp.multiply,
 }
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # dtypes the Mosaic TPU dialect handles natively; anything else (f16, f64,

@@ -22,16 +22,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.platform import pallas_interpret as _interpret
+
 _LANES = 128
 # flat payloads reshape to (-1, _COLS) like the combine dataplane: wider
 # rows mean 8x fewer grid steps, which is the difference between a
 # grid-overhead-bound lane and an HBM-bound one at large sizes
 _COLS = 1024
 _BLOCK_ROWS = 512  # 512x1024 fp32 = 2 MiB per block
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _cast_kernel(x_ref, o_ref):
@@ -243,30 +241,42 @@ _BS_FP8 = {
 }
 
 
-def _bs_fp8_cast(v: jax.Array, qname: str) -> jax.Array:
-    """Bit-exact ml_dtypes RNE f32 -> fp8 encode (see _BS_FP8)."""
+def _bs_fp8_cast(v: jax.Array, qname: str,
+                 sign_of: jax.Array | None = None) -> jax.Array:
+    """Bit-exact ml_dtypes RNE f32 -> fp8 encode (see _BS_FP8). The
+    code's sign bit comes from ``sign_of`` (default ``v``): a caller
+    that encodes ``x * inv`` with ``inv > 0`` passes ``x``. The product
+    has x's sign, except that IEEE leaves a NaN product's sign open
+    (numpy's keeps x's; a backend need not).
+
+    The bit math runs in int32 with logical shifts: Mosaic legalizes
+    neither unsigned min nor 8-bit vector shifts, and int32 wraps on
+    the one overflow (NaN payloads + the rounding bias) into the same
+    bit pattern a uint32 would hold, which the logical shift reads back
+    as the unsigned value."""
     shift, rebias, nmin, clamp, dscale, nan_code = _BS_FP8[qname]
-    u = jax.lax.bitcast_convert_type(v, jnp.uint32)
-    sign = (u >> 31).astype(jnp.uint8) << 7
-    a = u & jnp.uint32(0x7FFFFFFF)
+    srl = jax.lax.shift_right_logical
+    u = jax.lax.bitcast_convert_type(v, jnp.int32)
+    sign = srl(jax.lax.bitcast_convert_type(
+        v if sign_of is None else sign_of, jnp.int32), 31) << 7
+    a = u & 0x7FFFFFFF
     # normals/overflow: integer round-nearest-even of the top mantissa
     # bits, exponent rebiasing folded into the code arithmetic; rounding
     # carries ripple into the exponent field for free
-    lsb = (a >> shift) & jnp.uint32(1)
-    rne = (a + jnp.uint32((1 << (shift - 1)) - 1) + lsb) >> shift
-    code = jnp.minimum(rne - jnp.uint32(rebias), jnp.uint32(clamp))
+    lsb = srl(a, shift) & 1
+    rne = srl(a + ((1 << (shift - 1)) - 1) + lsb, shift)
+    code = jnp.minimum(rne - rebias, clamp)
     # target denormals: scale into code units (exact, power of two) and
     # RNE in f32 — jnp.round is half-to-even
-    code_d = jnp.round(jnp.abs(v) * jnp.float32(dscale)).astype(jnp.uint32)
-    code = jnp.where(a < jnp.uint32(nmin), code_d, code)
+    code_d = jnp.round(jnp.abs(v) * jnp.float32(dscale)).astype(jnp.int32)
+    code = jnp.where(a < nmin, code_d, code)
     if nan_code is not None:
-        code = jnp.where(a > jnp.uint32(0x7F800000),
-                         jnp.uint32(nan_code), code)
-    bits = sign | code.astype(jnp.uint8)
+        code = jnp.where(a > 0x7F800000, nan_code, code)
+    bits = (sign | code).astype(jnp.int8)
     return jax.lax.bitcast_convert_type(bits, jnp.dtype(qname))
 
 
-def _bs_encode(v: jax.Array, qdtype) -> jax.Array:
+def _bs_encode(v: jax.Array, sign_of: jax.Array, qdtype) -> jax.Array:
     """f32 -> wire cast with the reference's saturation rules. fp8 rides
     the bit-exact encoder above (RNE; e4m3fn overflow -> NaN, e5m2 ->
     inf, the ml_dtypes semantics); int8 rounds half-to-even, clips to
@@ -275,33 +285,91 @@ def _bs_encode(v: jax.Array, qdtype) -> jax.Array:
         return jnp.where(jnp.isfinite(v),
                          jnp.clip(jnp.round(v), -127.0, 127.0),
                          jnp.float32(0.0)).astype(jnp.int8)
-    return _bs_fp8_cast(v, jnp.dtype(qdtype).name)
+    return _bs_fp8_cast(v, jnp.dtype(qdtype).name, sign_of)
+
+
+def _bs_div(a: jax.Array, b: jax.Array) -> jax.Array:
+    """IEEE round-to-nearest-even ``a / b``, exact on every backend.
+
+    A TPU's f32 divide is not correctly rounded (its scales came out up
+    to 3 ULP off the numpy reference on a v5e), so the per-block scale
+    and its reciprocal are divided here: the backend's own quotient of
+    the 24-bit significands only estimates the 25-bit quotient, which
+    is then corrected against the exact integer remainder (12-bit limbs
+    keep every product inside int32) and rounded half-even on the guard
+    and sticky bits. Exact for a >= 0 normal and b > 0 normal finite,
+    subnormal and overflowing quotients included; inf and NaN ``a``
+    pass through and a subnormal ``a`` gives 0."""
+    srl = jax.lax.shift_right_logical
+    ia = jax.lax.bitcast_convert_type(a, jnp.int32)
+    ib = jax.lax.bitcast_convert_type(b, jnp.int32)
+    ea, eb = srl(ia, 23), srl(ib, 23)
+    ma = (ia & 0x7FFFFF) | 0x800000
+    mb = (ib & 0x7FFFFF) | 0x800000
+    lt = (ma < mb).astype(jnp.int32)
+    m = ma << lt                        # m / mb in [1, 2)
+    e = ea - eb + 127 - lt              # biased exponent of the quotient
+    bf = mb.astype(jnp.float32)
+    q = (m.astype(jnp.float32) / bf * jnp.float32(1 << 24)).astype(jnp.int32)
+    # r = m * 2^24 - q * mb, exactly: the true r is a few mb at most, so
+    # the 2^24 limb difference is tiny and nothing overflows
+    qh, ql = srl(q, 12), q & 0xFFF
+    bh, bl = srl(mb, 12), mb & 0xFFF
+    mid = qh * bl + ql * bh
+    r = (((m - qh * bh - srl(mid, 12)) << 24) - ((mid & 0xFFF) << 12)
+         - ql * bl)
+    k = jnp.floor(r.astype(jnp.float32) / bf).astype(jnp.int32)
+    q, r = q + k, r - k * mb
+    q, r = jnp.where(r < 0, q - 1, q), jnp.where(r < 0, r + mb, r)
+    q, r = jnp.where(r >= mb, q + 1, q), jnp.where(r >= mb, r - mb, r)
+    # keep 24 bits for a normal quotient, fewer for a subnormal one
+    drop = jnp.clip(2 - e, 1, 26)
+    kept = srl(q, drop)
+    guard = srl(q, drop - 1) & 1
+    sticky = ((q & ((1 << (drop - 1)) - 1)) != 0) | (r != 0)
+    kept = kept + (guard & (sticky.astype(jnp.int32) | (kept & 1)))
+    bits = jnp.where(e >= 1, ((e - 1) << 23) + kept, kept)
+    bits = jnp.where(e > 254, 0x7F800000, bits)     # overflow -> inf
+    out = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    out = jnp.where(ea == 0, jnp.float32(0.0), out)
+    return jnp.where(ea == 255, a, out)
 
 
 def _bs_quant_rows(x: jax.Array, qdtype, one: jax.Array,
                    qmax: jax.Array):
     """Shared quantize body: x (R, block) f32 -> (q, scales (R, 1)).
 
-    ``one``/``qmax`` are RUNTIME scalars (SMEM operands), not literals:
-    XLA strength-reduces division by a constant into multiplication by
-    its reciprocal (1 ULP off IEEE), which would break bit-identity with
-    the numpy reference — a division by a runtime operand stays a true
-    division."""
+    ``one``/``qmax`` are RUNTIME scalars (SMEM operands), not literals,
+    so no compiler folds the scale arithmetic into constants; the two
+    divisions are exact (:func:`_bs_div`), as numpy's are."""
     amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)  # NaN-propagating
-    s = amax / qmax
+    s = _bs_div(amax, jnp.broadcast_to(qmax, amax.shape))
     good = (s >= _BS_FLT_MIN) & (s < jnp.inf)
     s = jnp.where(good, s, jnp.float32(1.0))
-    v = x * (one / s)                   # reciprocal-multiply, like numpy
-    return _bs_encode(v, qdtype), s
+    inv = _bs_div(jnp.broadcast_to(one, s.shape), s)
+    v = x * inv                         # reciprocal-multiply, like numpy
+    return _bs_encode(v, x, qdtype), s
+
+
+def _bs_block_rows(block: int) -> int:
+    """Rows per grid step: ~1 MiB of f32 per VMEM block, the largest at
+    which the quantize kernel compiles for a v5e: at 2 MiB (4096 rows
+    at block 128) its scale math (:func:`_bs_div`, (R, 1) columns each
+    padded to whole (8, 128) tiles) needs 4 KiB over the 16 MiB scoped
+    VMEM (``scripts/codec_rows_compile.py``). The dequantize and combine
+    kernels would compile at 2 MiB, but all three share one payload
+    geometry (:func:`_bs_geometry`), and dequantize ran no slower at
+    1 MiB on the chip."""
+    return max(8, (1 << 20) // (4 * block))
 
 
 def _bs_geometry(n: int, block: int) -> tuple[int, int, int]:
     """(nb, row_block, padded_rows): blocks-per-payload, grid row chunk
-    (~2 MiB of f32 per VMEM block), and nb padded up to a multiple of
+    (:func:`_bs_block_rows`), and nb padded up to a multiple of
     the chunk so every grid step sees a full block (padded rows are
     zeros -> scale 1.0, payload 0; sliced off after the call)."""
     nb = -(-n // block)
-    rows = max(8, (1 << 21) // (4 * block))
+    rows = _bs_block_rows(block)
     rows = min(rows, nb) if nb >= 8 else nb
     return nb, rows, nb + ((-nb) % rows)
 
@@ -329,10 +397,10 @@ def _bs_scalars(qname: str) -> tuple[jax.Array, jax.Array]:
 
     These must be built EAGERLY (outside any trace) and enter every
     jitted program as ARGUMENTS: created inside a trace they become
-    compile-time constants, and then either XLA strength-reduces the
-    divisions into reciprocal multiplies or LLVM folds the ``* one``
-    guard and contracts dequant-multiply + accumulate into an fma —
-    both 1 ULP off the numpy reference. (optimization_barrier does not
+    compile-time constants, and then LLVM folds the ``* one`` guard and
+    contracts dequant-multiply + accumulate into an fma — 1 ULP off the
+    numpy reference. (The divisions no longer depend on it: _bs_div
+    corrects its quotient exactly.) (optimization_barrier does not
     help: constants still reach LLVM as immediates.) The bs_* wrappers
     build them eagerly per call; the ring collective programs thread
     them through shard_map as replicated inputs."""
@@ -353,7 +421,7 @@ def _bs_quant_call(tiles: jax.Array, one: jax.Array, qmax: jax.Array,
         q_ref[:] = q
         s_ref[:] = s
 
-    R = min(max(8, (1 << 21) // (4 * block)), rows)
+    R = min(_bs_block_rows(block), rows)
     smem = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
@@ -378,7 +446,7 @@ def _bs_dequant_call(qtiles: jax.Array, scales: jax.Array, block: int):
     def kernel(q_ref, s_ref, o_ref):
         o_ref[:] = q_ref[:].astype(jnp.float32) * s_ref[:]
 
-    R = min(max(8, (1 << 21) // (4 * block)), rows)
+    R = min(_bs_block_rows(block), rows)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(qtiles.shape, jnp.float32),
@@ -423,7 +491,7 @@ def _bs_combine_call(qtiles: jax.Array, scales: jax.Array,
         else:
             out_refs[0][:] = acc
 
-    R = min(max(8, (1 << 21) // (4 * block)), rows)
+    R = min(_bs_block_rows(block), rows)
     row_spec = pl.BlockSpec((R, block), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
     s_spec = pl.BlockSpec((R, 1), lambda i: (i, 0),

@@ -25,7 +25,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size as _axis_size
 
 from ..constants import ReduceFunc
 from .collectives import ring_allreduce_shard, axis_reduce
@@ -136,7 +135,7 @@ def bucketed_allreduce(grads, axis_name: str,
         raise ValueError(
             f"plan built for {plan.n_leaves} leaves, got {len(leaves)}")
     out: list = [None] * plan.n_leaves
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     for bucket in plan.buckets:
         fused = _flatten_bucket(bucket, leaves)
         if algorithm == "ring":
@@ -176,7 +175,7 @@ def make_ddp_train_step(loss_fn, optimizer, axis_name: str = "dp",
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = jax.tree.map(lambda p, u: p + u, params, updates)
         loss = axis_reduce(loss, axis_name, ReduceFunc.SUM) / \
-            _axis_size(axis_name)
+            jax.lax.axis_size(axis_name)
         return params, opt_state, loss
 
     return train_step

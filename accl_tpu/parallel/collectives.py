@@ -29,8 +29,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import shard_map as _shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -118,7 +116,7 @@ def ring_reduce_scatter_shard(x: jnp.ndarray, axis_name: str,
     Chunk schedule parity: firmware reduce_scatter (c:860-939) — send chunk
     me+1, round i reduces+forwards chunk me+1+i, final round keeps chunk me.
     """
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     op = _REDUCE_OPS[func]
     perm = _ring_perm(W)
@@ -140,7 +138,7 @@ def ring_allgather_shard(x: jnp.ndarray, axis_name: str,
     Parity: firmware allgather (c:727-828) — send own chunk along the ring;
     chunk me+i arrives at round i (decreasing-rank flow).
     """
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     perm = _ring_perm(W)
     out = jnp.zeros((W,) + x.shape, x.dtype)
@@ -162,7 +160,7 @@ def ring_allreduce_shard(x: jnp.ndarray, axis_name: str,
     """Ring allreduce = ring reduce-scatter + ring allgather over W chunks
     of the flattened shard (firmware allreduce, c:942-1098). ``x``: any
     shape, same on all ranks; returns the elementwise reduction."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1)
     pad = (-flat.size) % W
@@ -199,7 +197,7 @@ def ring_reduce_scatter_bs_shard(x: jnp.ndarray, axis_name: str,
     """Block-scaled ring reduce-scatter. ``x``: (W, chunk...) per shard;
     returns this rank's reduced chunk in f32 accumulation semantics,
     cast back to ``x.dtype``."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     perm = _ring_perm(W)
 
@@ -228,7 +226,7 @@ def ring_allgather_bs_shard(x: jnp.ndarray, axis_name: str, wire_dtype,
     """Block-scaled ring allgather. ``x``: (chunk...,) per shard; returns
     (W, chunk...). The own chunk lands exact; remote chunks carry one
     quantization regardless of relay distance (bytes forwarded as-is)."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     perm = _ring_perm(W)
     out = jnp.zeros((W,) + x.shape, x.dtype)
@@ -250,7 +248,7 @@ def ring_allreduce_bs_shard(x: jnp.ndarray, axis_name: str,
     """Block-scaled ring allreduce = quantized reduce-scatter + quantized
     allgather over W chunks of the flattened shard (the EQuARX-style
     fused quantized collective)."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1)
     pad = (-flat.size) % W
@@ -309,7 +307,7 @@ def multi_axis_ring_allreduce_shard(x: jnp.ndarray,
         # shard, so phase j moves a 1/prod(earlier sizes) fraction of
         # the part on axis order[j] — the first (biggest) phase is axis i
         for ax in order:
-            W = _axis_size(ax)
+            W = jax.lax.axis_size(ax)
             y = ring_reduce_scatter_shard(y.reshape(W, -1), ax, func,
                                           wire_dtype)
         # allgather cascade back up in reverse
@@ -411,7 +409,7 @@ def xla_compressed_allreduce_shard(x: jnp.ndarray, axis_name: str,
     accumulation: compressed reduce-scatter (all_to_all + local upcast
     reduce) then compressed all-gather — the firmware's fused 2-phase
     structure (c:942-1098) lowered to XLA's fused collectives."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1)
     pad = (-flat.size) % W
@@ -632,14 +630,14 @@ class MeshCollectives:
             # check_vma off: shard_map has no replication rule for
             # pallas_call; every bs program output is rank-varying anyway
             f = self._bs_shard_fn(op, func, wire, qblock)
-            fn = _shard_map(f, mesh=self.mesh,
-                            in_specs=(P(ax, None), P(None, None),
-                                      P(None, None)),
-                            out_specs=P(ax, None), check_vma=False)
+            fn = jax.shard_map(f, mesh=self.mesh,
+                               in_specs=(P(ax, None), P(None, None),
+                                         P(None, None)),
+                               out_specs=P(ax, None), check_vma=False)
             prog = self._cache[ck] = self._bs_wrap(fn, wire)
             return prog
         f = self._shard_fn(op, algorithm, func, wire, root)
-        fn = _shard_map(f, mesh=self.mesh, in_specs=P(ax, None),
+        fn = jax.shard_map(f, mesh=self.mesh, in_specs=P(ax, None),
                            out_specs=P(ax, None))
         prog = self._cache[ck] = jax.jit(fn)
         return prog
@@ -663,9 +661,9 @@ class MeshCollectives:
             def g(x, one, qmax):
                 return f(x[None], one, qmax)[0]
 
-            fn = _shard_map(g, mesh=self.mesh,
-                            in_specs=(P(ax), P(None, None), P(None, None)),
-                            out_specs=P(ax), check_vma=False)
+            fn = jax.shard_map(g, mesh=self.mesh,
+                               in_specs=(P(ax), P(None, None), P(None, None)),
+                               out_specs=P(ax), check_vma=False)
             prog = self._cache[ck] = self._bs_wrap(fn, wire)
             return prog
         f = self._shard_fn(op, algorithm, func, wire, root)
@@ -673,7 +671,7 @@ class MeshCollectives:
         def g(x):
             return f(x[None])[0]
 
-        fn = _shard_map(g, mesh=self.mesh, in_specs=P(ax),
+        fn = jax.shard_map(g, mesh=self.mesh, in_specs=P(ax),
                            out_specs=P(ax))
         prog = self._cache[ck] = jax.jit(fn)
         return prog
@@ -735,7 +733,7 @@ class MeshCollectives:
         def f(x):
             return send_recv(x[0], list(pairs), ax)[None]
 
-        fn = _shard_map(f, mesh=self.mesh, in_specs=P(ax, None),
+        fn = jax.shard_map(f, mesh=self.mesh, in_specs=P(ax, None),
                            out_specs=P(ax, None))
         self._evict_exchange_programs()
         prog = self._cache[ck] = jax.jit(fn)
@@ -772,7 +770,7 @@ class MeshCollectives:
         def g(x):
             return send_recv(x, list(pairs), ax)
 
-        fn = _shard_map(g, mesh=self.mesh, in_specs=P(ax),
+        fn = jax.shard_map(g, mesh=self.mesh, in_specs=P(ax),
                            out_specs=P(ax))
         self._evict_exchange_programs()
         prog = self._cache[ck] = jax.jit(fn)

@@ -31,9 +31,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import distributed_is_initialized as _distributed_is_initialized
-from ..utils.compat import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..constants import ReduceFunc
@@ -56,7 +53,7 @@ def distributed_init(coordinator_address: str | None = None,
     """
     # NOTE: must not touch jax.process_count()/jax.devices() here — reading
     # them initializes the XLA backends, after which initialize() raises.
-    if _distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return True
     if coordinator_address is None and num_processes is None:
         import os
@@ -138,7 +135,7 @@ def hierarchical_allreduce(x: jnp.ndarray, ici_axis: str = "ici",
     ``wire_dtype`` compresses the DCN hop only — the slow fabric is where
     wire precision pays (ACCLCompressionFlags analog).
     """
-    W = _axis_size(ici_axis)
+    W = jax.lax.axis_size(ici_axis)
     n = x.shape[0]
     pad = (-n) % W
     if pad:
@@ -190,7 +187,7 @@ def hierarchical_allreduce_sharded(x: jax.Array, mesh: Mesh,
             return hierarchical_allreduce(
                 s[0], ici_axis, dcn_axis, func, wire_dtype)[None]
 
-        run = jax.jit(_shard_map(body, mesh=mesh, in_specs=spec,
+        run = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
                                     out_specs=spec))
         _PROGRAM_CACHE[key] = run
     return run(x)

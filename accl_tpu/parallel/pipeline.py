@@ -19,8 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import shard_map as _shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -44,7 +42,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches: jnp.ndarray,
 
     Returns (n_micro, mb, ...) outputs.
     """
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     n_micro = microbatches.shape[0]
     T = n_micro + W - 1
@@ -97,7 +95,7 @@ def _pipeline_program(stage_fn, mesh: Mesh, axis_name: str,
     the cache retains each closure."""
     pspecs = {k: P(axis_name, *([None] * nd)) for k, nd in param_keys_ndims}
 
-    @functools.partial(_shard_map, mesh=mesh, in_specs=(pspecs, P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(pspecs, P()),
                        out_specs=P())
     def f(params, mb):
         local = jax.tree.map(lambda x: x[0], params)

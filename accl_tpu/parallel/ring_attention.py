@@ -25,8 +25,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import shard_map as _shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -71,7 +69,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     bytes on EVERY hop; the update rule groups each KV head's queries).
     Returns (B, H, S_local, D) in q.dtype.
     """
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, H, S, D = q.shape
     Hkv = k.shape[1]
@@ -122,7 +120,7 @@ def _ring_program(mesh: Mesh, axis_name: str, causal: bool,
     hits jax.jit's trace cache instead of rebuilding the closure."""
     spec = P(None, None, axis_name, None)
 
-    @functools.partial(_shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec)
     def f(q, k, v):
         return ring_attention(q, k, v, axis_name, causal, sm_scale)

@@ -37,8 +37,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import shard_map as _shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -66,7 +64,7 @@ def tree_reduce_shard(x: jnp.ndarray, root: int, outer: str, inner: str,
     """Two-phase reduction to root: columns reduce along ``outer`` into the
     root's row, the root's row reduces along ``inner`` into the root.
     Non-root ranks return zeros."""
-    I = _axis_size(inner)
+    I = jax.lax.axis_size(inner)
     ro, ri = _split_root(root, I)
     partial = axis_reduce(x, outer, func)   # every row holds the column sums
     full = axis_reduce(partial, inner, func)  # global reduction everywhere
@@ -176,7 +174,7 @@ def binomial_bcast_shard(x: jnp.ndarray, root: int,
     from vranks [0, 2^k) to [2^k, 2^(k+1)). ``wire_dtype`` casts each
     hop's payload for transit (ETH_COMPRESSED, ccl_offload_control.c:
     533-556); the root's copy never crosses the wire and stays exact."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     if W == 1:
         return x
     me = lax.axis_index(axis_name)
@@ -205,7 +203,7 @@ def binomial_gather_shard(x: jnp.ndarray, root: int,
     single-sender round truncates). Either way O(W log W / 2), vs
     all_gather+mask's W(W-1). ``gather_rounds`` is the byte-exact
     schedule."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     if W == 1:
         return x[None]
     me = lax.axis_index(axis_name)
@@ -240,7 +238,7 @@ def binomial_scatter_shard(x: jnp.ndarray, root: int,
     ``binomial_gather_shard`` with the byte-exact schedule in
     ``scatter_rounds``; O(W log W / 2) chunks total vs masked
     psum_scatter's reduce-scatter-class W(W-1)."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     if W == 1:
         return x[0]
     me = lax.axis_index(axis_name)
@@ -324,7 +322,7 @@ class Tree2DCollectives:
         else:
             raise NotImplementedError(op)
 
-        fn = _shard_map(f, mesh=self.mesh, in_specs=self._spec(),
+        fn = jax.shard_map(f, mesh=self.mesh, in_specs=self._spec(),
                            out_specs=self._spec())
         prog = self._cache[ck] = jax.jit(fn)
         return prog

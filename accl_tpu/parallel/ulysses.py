@@ -21,8 +21,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import shard_map as _shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -32,7 +30,7 @@ from ..ops.attention import flash_attention
 def seq_to_heads(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """(B, H, S_local, D) seq-sharded -> (B, H/W, S_global, D) head-sharded.
     Requires H % W == 0."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     B, H, S, D = x.shape
     assert H % W == 0, f"heads {H} not divisible by axis size {W}"
     # split heads across ranks, gather sequence: all_to_all moves the head
@@ -58,7 +56,7 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kernel routes each Q head to its KV head on the other side). When
     H_kv doesn't divide the axis size, KV repeats minimally (to one head
     per rank if that divides, else to H). Returns (B, H, S_local, D)."""
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     H, Hkv = q.shape[1], k.shape[1]
     qh = seq_to_heads(q, axis_name)
     if Hkv % W and H != Hkv:
@@ -81,7 +79,7 @@ def _ulysses_program(mesh: Mesh, axis_name: str, causal: bool,
 
     # check_vma=False: the pallas interpreter's internal slices don't carry
     # varying-axis types yet (jax suggests this exact workaround)
-    @functools.partial(_shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     def f(q, k, v):
         return ulysses_attention(q, k, v, axis_name, causal, sm_scale)

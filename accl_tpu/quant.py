@@ -239,6 +239,56 @@ def _np_dequant(scales: np.ndarray, q: np.ndarray, block: int
                 * np.repeat(scales, block)[:q.size]).astype(np.float32)
 
 
+def ring_reduce_scatter_reference(chunks, func: ReduceFunc,
+                                  qdtype: np.dtype, block: int) -> list:
+    """Numpy reference of the device tier's block-scaled ring
+    reduce-scatter (parallel.collectives.ring_reduce_scatter_bs_shard).
+    ``chunks[r]``: rank r's (W, n) chunk view. Rank r starts by
+    quantizing chunk (r+1)%W, receives from (r+1)%W each hop, and fuses
+    func(local chunk, dequant) with fresh scales per hop. Returns
+    out[r] = rank r's reduced chunk r."""
+    W = len(chunks)
+    npf = _NP_FUNCS[ReduceFunc(func)]
+    state = {r: _np_quantize(chunks[r][(r + 1) % W], qdtype, block)
+             for r in range(W)}
+    out = {}
+    for i in range(1, W):
+        nxt = {}
+        for r in range(W):
+            s, q = state[(r + 1) % W]
+            acc = npf(chunks[r][(r + 1 + i) % W], _np_dequant(s, q, block))
+            if i < W - 1:
+                nxt[r] = _np_quantize(acc, qdtype, block)
+            else:
+                out[r] = acc
+        state = nxt
+    return [out[r] for r in range(W)]
+
+
+def ring_allgather_reference(mine, qdtype: np.dtype, block: int) -> list:
+    """Numpy reference of the block-scaled ring allgather: own chunk
+    exact, remote chunks carry exactly ONE quantization (relays forward
+    bytes)."""
+    W = len(mine)
+    enc = [_np_quantize(mine[o], qdtype, block) for o in range(W)]
+    return [np.concatenate([mine[o] if o == r
+                            else _np_dequant(enc[o][0], enc[o][1], block)
+                            for o in range(W)]) for r in range(W)]
+
+
+def ring_allreduce_reference(ins, func: ReduceFunc, qdtype: np.dtype,
+                             block: int) -> list:
+    """Numpy reference of the block-scaled ring allreduce: the
+    reduce-scatter then allgather above over zero-padded equal chunks."""
+    W = len(ins)
+    n = ins[0].size
+    pad = (-n) % W
+    chunks = [np.concatenate([x, np.zeros(pad, np.float32)]).reshape(W, -1)
+              for x in ins]
+    mine = ring_reduce_scatter_reference(chunks, func, qdtype, block)
+    return [o[:n] for o in ring_allgather_reference(mine, qdtype, block)]
+
+
 # -- packed codec (the executor's entry points) -----------------------------
 
 def quantize_packed(x: np.ndarray, qdtype, block: int) -> np.ndarray:

@@ -24,7 +24,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from accl_tpu.utils.compat import shard_map as _shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -229,7 +228,7 @@ def sweep_collective(mesh: Mesh, op: str, sizes: Sequence[int],
                 out = lax.fori_loop(0, K, lambda i, a: body(a), x[0])
                 return jnp.sum(out.reshape(-1)[:1])[None]
 
-            f = _shard_map(shard_fn, mesh=mesh, in_specs=spec,
+            f = jax.shard_map(shard_fn, mesh=mesh, in_specs=spec,
                               out_specs=P(spec[0]), check_vma=False)
             return jax.jit(lambda v: f(v)[0])
 

@@ -28,7 +28,7 @@ MODULES = (
     "accl_tpu.parallel.collectives",
     "accl_tpu.parallel.ulysses",
     "accl_tpu.models.llama",
-    "accl_tpu.utils.compat",
+    "accl_tpu.utils.platform",
 )
 
 

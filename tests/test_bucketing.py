@@ -6,7 +6,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from accl_tpu.utils.compat import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from accl_tpu.constants import ReduceFunc
@@ -68,7 +67,7 @@ def test_bucketed_allreduce_matches_mean(algorithm, wire):
                                  wire_dtype=wire, algorithm=algorithm)
         return jax.tree.map(lambda x: x[None], out)
 
-    f = jax.jit(_shard_map(
+    f = jax.jit(jax.shard_map(
         shard_fn, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))
     out = f(jax.device_put(stacked, sharding))
     golden = jax.tree.map(lambda *xs: np.mean(np.stack(xs), 0), *trees)
@@ -116,7 +115,7 @@ def test_ddp_train_step_matches_fullbatch():
         new_p, new_s, l = step(jax.tree.map(lambda a: a, p), s, x)
         return new_p, new_s, l[None]
 
-    f = jax.jit(_shard_map(
+    f = jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(), P("dp")),
         out_specs=(P(), P(), P("dp")),

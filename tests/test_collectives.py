@@ -11,7 +11,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from accl_tpu.utils.compat import shard_map as _shard_map
 
 from accl_tpu.constants import ReduceFunc
 from accl_tpu.parallel import MeshCollectives, cpu_mesh
@@ -225,7 +224,7 @@ def test_ring_allreduce_fp8_wire():
         return ring_allreduce_shard(
             s[0], "r", wire_dtype=jnp.float8_e4m3fn)[None]
 
-    out = np.asarray(jax.jit(_shard_map(
+    out = np.asarray(jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P("r", None), out_specs=P("r", None)))(x))
     golden = np.asarray(x).sum(0)
     # fp8 e4m3 has ~2 decimal digits; scale-corrected error stays small
@@ -234,7 +233,7 @@ def test_ring_allreduce_fp8_wire():
     def body16(s):
         return ring_allreduce_shard(s[0], "r",
                                     wire_dtype=jnp.bfloat16)[None]
-    out16 = np.asarray(jax.jit(_shard_map(
+    out16 = np.asarray(jax.jit(jax.shard_map(
         body16, mesh=mesh, in_specs=P("r", None),
         out_specs=P("r", None)))(x))
     assert (np.abs(out16[0] - golden).mean()
@@ -265,7 +264,7 @@ def test_fused_stream_collective_single_program():
         summed = ring_allreduce_shard(produced, "r")  # collective
         return jax.nn.relu(summed - 1.0)[None]        # consumer "kernel"
 
-    prog = jax.jit(_shard_map(fused, mesh=mesh, in_specs=P("r", None),
+    prog = jax.jit(jax.shard_map(fused, mesh=mesh, in_specs=P("r", None),
                                  out_specs=P("r", None)))
     out = np.asarray(prog(x))
     golden = np.maximum(np.sum(np.tanh(np.asarray(x)) * 2.0, axis=0) - 1.0,
@@ -304,7 +303,7 @@ def test_multi_axis_ring_allreduce_drives_every_axis():
     def f(x):
         return multi_axis_ring_allreduce_shard(x[0], ("a", "b", "c"))[None]
 
-    g = jax.jit(_shard_map(f, mesh=mesh,
+    g = jax.jit(jax.shard_map(f, mesh=mesh,
                               in_specs=P(("a", "b", "c"), None),
                               out_specs=P(("a", "b", "c"), None)))
     rng = np.random.default_rng(0)

@@ -8,7 +8,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from accl_tpu.utils.compat import set_mesh as _set_mesh
 
 from accl_tpu.models import Llama, LlamaConfig
 
@@ -98,7 +97,7 @@ def test_sharded_forward_on_mesh(tiny):
     sharded = model.shard_params(params, mesh)
     tokens = jax.device_put(
         jnp.zeros((4, 16), jnp.int32), NamedSharding(mesh, P("dp", None)))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits = jax.jit(lambda p, t: model.forward(p, t, dp="dp"))(sharded,
                                                                     tokens)
     with jax.default_device(CPU):
@@ -186,7 +185,7 @@ def test_sharded_flash_attention_matches_unsharded(tiny, n_kv, shape):
 
     mesh = Mesh(np.array(jax.devices()[:shape[0] * shape[1]])
                 .reshape(shape), ("dp", "tp"))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.shard_params(params_host, mesh)
         tokens = jax.device_put(tokens_host,
                                 NamedSharding(mesh, P("dp", None)))
@@ -216,13 +215,13 @@ def test_tensor_parallel_train_rejects_indivisible_heads(tiny):
     params = model.init(jax.random.key(0))
     tokens = jnp.zeros((4, 16), jnp.int32)
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "tp"))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="must divide the head counts"):
             model.forward(params, tokens, dp="dp", mesh=mesh)
     # batch indivisible by dp: the dispatch raises a clear ValueError at
     # trace time instead of a cryptic shard_map divisibility error
     mesh2 = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("dp", "tp"))
-    with _set_mesh(mesh2):
+    with jax.set_mesh(mesh2):
         with pytest.raises(ValueError, match="not divisible by dp"):
             jax.jit(lambda p, t: model.forward(p, t, dp="dp", mesh=mesh2)
                     ).trace(params, jnp.zeros((3, 16), jnp.int32))
@@ -248,7 +247,7 @@ def test_sequence_parallel_llama_via_ring_attention(tiny):
     ref = jax.jit(model.forward)(params_host, jnp.asarray(tokens_host))
 
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "sp"))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.device_put(params_host, NamedSharding(mesh, P()))
         tokens = jax.device_put(tokens_host,
                                 NamedSharding(mesh, P("dp", "sp")))
@@ -290,7 +289,7 @@ def test_tensor_parallel_generate_matches_unsharded(n_kv, tp_size):
 
     mesh = Mesh(np.array(jax.devices()[:2 * tp_size]).reshape(2, tp_size),
                 ("dp", "tp"))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.shard_params(params_host, mesh)
         p_sh = jax.device_put(prompt, NamedSharding(mesh, P("dp", None)))
         out = model.generate(params, p_sh, max_new=6, mesh=mesh, dp="dp")
@@ -337,7 +336,7 @@ def test_moe_llama_trains_and_decodes():
     # MoE + dp x tp sharding: the expert weights carry 4-D specs
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "tp"))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sp = model.shard_params(params, mesh)
         tok = jax.device_put(np.asarray(tokens),
                              NamedSharding(mesh, P("dp", None)))

@@ -11,7 +11,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from accl_tpu.utils.compat import shard_map as _shard_map
 
 from accl_tpu.constants import ReduceFunc
 from accl_tpu.parallel import (hierarchical_allreduce_sharded, hybrid_mesh,
@@ -98,7 +97,7 @@ def test_dp_grad_sync_over_hybrid_mesh(mesh):
         g = hierarchical_allreduce(g, "ici", "dcn") / W
         return g[None]
 
-    f = jax.jit(_shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None), P(("dcn", "ici"))),
         out_specs=P(("dcn", "ici"))))

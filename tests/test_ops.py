@@ -240,7 +240,10 @@ def test_flash_attention_grad_matches_dense(hkv, causal):
         return jnp.sum(jnp.square(o))
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    # the f32 reference at f32 precision on every backend (a TPU's
+    # default matmul is one bf16 pass)
+    with jax.default_matmul_precision("highest"):
+        gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for got, want, name in zip(gf, gd, "qkv"):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-3, atol=2e-3, err_msg=f"d{name}")
@@ -248,9 +251,9 @@ def test_flash_attention_grad_matches_dense(hkv, causal):
 
 def _decode_reference(q, kc, vc, kvlen):
     B, H, S_new, D = q.shape
-    Hkv = kc.shape[2]
-    kk = jnp.repeat(kc[:, :kvlen].transpose(0, 2, 1, 3), H // Hkv, 1)
-    vv = jnp.repeat(vc[:, :kvlen].transpose(0, 2, 1, 3), H // Hkv, 1)
+    Hkv = kc.shape[1]
+    kk = jnp.repeat(kc[:, :, :kvlen], H // Hkv, 1)
+    vv = jnp.repeat(vc[:, :, :kvlen], H // Hkv, 1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    kk.astype(jnp.float32)) * (D ** -0.5)
     qpos = kvlen - S_new + jnp.arange(S_new)
@@ -262,17 +265,33 @@ def _decode_reference(q, kc, vc, kvlen):
 
 @pytest.mark.parametrize("s_new,kvlen", [(1, 37), (3, 64), (5, 100), (1, 1)])
 def test_flash_decode_matches_dense(s_new, kvlen):
-    """Decode kernel over a part-full cache in its native (B, T, Hkv, D)
+    """Decode kernel over a part-full cache in its native (B, Hkv, T, D)
     layout: dynamic fill length (traced scalar), GQA routing, causal
     offset for chunked prefill, and a cache length that does NOT divide
     the block size (tail blocks are out-of-bounds-masked)."""
     from accl_tpu.ops.attention import flash_decode
     B, H, Hkv, D, T = 2, 8, 2, 32, 100
     ks = jax.random.split(jax.random.key(5), 3)
-    kc = jax.random.normal(ks[0], (B, T, Hkv, D), jnp.float32)
-    vc = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
+    kc = jax.random.normal(ks[0], (B, Hkv, T, D), jnp.float32)
+    vc = jax.random.normal(ks[1], (B, Hkv, T, D), jnp.float32)
     q = jax.random.normal(ks[2], (B, H, s_new, D), jnp.float32)
     out = flash_decode(q, kc, vc, jnp.int32(kvlen), block_k=32)
+    ref = _decode_reference(q, kc, vc, kvlen)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=8e-3, atol=8e-3)
+
+
+def test_flash_decode_tiles_long_prefill_rows():
+    """A chunked prefill whose q-group rows (group * S_new) exceed one
+    512-row block runs as several row blocks: each keeps its own causal
+    offset into the new tokens."""
+    from accl_tpu.ops.attention import flash_decode
+    B, H, Hkv, D, T, s_new, kvlen = 1, 8, 1, 32, 256, 100, 200
+    ks = jax.random.split(jax.random.key(7), 3)
+    kc = jax.random.normal(ks[0], (B, Hkv, T, D), jnp.float32)
+    vc = jax.random.normal(ks[1], (B, Hkv, T, D), jnp.float32)
+    q = jax.random.normal(ks[2], (B, H, s_new, D), jnp.float32)
+    out = flash_decode(q, kc, vc, jnp.int32(kvlen), block_k=64)
     ref = _decode_reference(q, kc, vc, kvlen)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=8e-3, atol=8e-3)
@@ -284,8 +303,8 @@ def test_flash_decode_one_program_many_lengths():
     from accl_tpu.ops.attention import flash_decode
     B, H, Hkv, D, T = 1, 4, 2, 16, 64
     ks = jax.random.split(jax.random.key(6), 3)
-    kc = jax.random.normal(ks[0], (B, T, Hkv, D), jnp.float32)
-    vc = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
+    kc = jax.random.normal(ks[0], (B, Hkv, T, D), jnp.float32)
+    vc = jax.random.normal(ks[1], (B, Hkv, T, D), jnp.float32)
     q = jax.random.normal(ks[2], (B, H, 1, D), jnp.float32)
     fn = jax.jit(lambda q, kc, vc, n: flash_decode(q, kc, vc, n, block_k=16))
     for kvlen in (1, 17, 40, 64):

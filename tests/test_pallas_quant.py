@@ -71,6 +71,14 @@ def _assert_bit_identical(got, ref, what: str):
     ref = np.asarray(ref)
     assert got.dtype == ref.dtype and got.shape == ref.shape, what
     gb, rb = _bits(got), _bits(ref)
+    if (jax.default_backend() == "tpu"
+            and jnp.issubdtype(ref.dtype, jnp.floating)):
+        # XLA on a v5e writes every NaN it touches with the sign bit
+        # clear: a bitcast or a copy of an fp8 array, and float ops. The
+        # kernels' own stores and the host transfer keep it
+        # (scripts/probe_fp8_nan.py). Only that sign bit is excused
+        sign = rb.dtype.type(1 << (8 * rb.itemsize - 1))
+        rb = np.where(np.isnan(ref.astype(np.float32)), rb & ~sign, rb)
     bad = gb != rb
     assert not bad.any(), (
         f"{what}: {int(bad.sum())}/{bad.size} bit mismatches, first at "
@@ -104,6 +112,37 @@ def test_fp8_decode_parity_256_codes(qd):
     assert np.isnan(ref).sum() == np.isnan(np.asarray(got)).sum()
     m = ~np.isnan(ref)
     _assert_bit_identical(got[m], ref[m], f"decode {qd.name}")
+
+
+def _normals(rng, n, lo=0x00800000, hi=0x7F000000):
+    return rng.integers(lo, hi, n, dtype=np.int64).astype(
+        np.uint32).view(np.float32)
+
+
+@pytest.mark.parametrize("case", ["general", "by_qmax", "reciprocal",
+                                  "subnormal_quotient"])
+def test_exact_division_matches_ieee(case):
+    """The codec's scale division is IEEE round-to-nearest-even (a TPU's
+    own f32 divide is not), subnormal and overflowing quotients
+    included."""
+    rng = np.random.default_rng(7)
+    n = 200_000
+    a, b = {
+        "general": lambda: (_normals(rng, n), _normals(rng, n)),
+        "by_qmax": lambda: (_normals(rng, n, 0x00800000, 0x7F7FFFFF),
+                            np.full(n, 448.0, np.float32)),
+        "reciprocal": lambda: (np.ones(n, np.float32), _normals(rng, n)),
+        "subnormal_quotient": lambda: (_normals(rng, n, hi=0x10000000),
+                                       _normals(rng, n, 0x30000000)),
+    }[case]()
+    got = np.asarray(jax.jit(comp._bs_div)(jnp.asarray(a), jnp.asarray(b)))
+    with np.errstate(over="ignore", under="ignore"):
+        ref = (a / b).astype(np.float32)
+    if jax.default_backend() == "tpu":
+        # a TPU flushes subnormal f32 values to zero
+        tiny = np.abs(ref) < np.finfo(np.float32).tiny
+        ref = np.where(tiny & (got == 0), got, ref)
+    _assert_bit_identical(got, ref, f"division {case}")
 
 
 # -- corpus bit-identity vs the numpy reference ------------------------------
@@ -174,59 +213,13 @@ def test_corpus_combine_edge_blocks_bit_identical(block):
 
 # -- quantized rings vs a numpy ring oracle ----------------------------------
 
-def _oracle_rs(chunks, func, qd, block):
-    """Reference block-scaled ring reduce-scatter. ``chunks[r]``: rank
-    r's (W, n) chunk view. Mirrors ring_reduce_scatter_bs_shard: rank r
-    starts by quantizing chunk (r+1)%W, receives from (r+1)%W each hop,
-    fuses func(local chunk, dequant) with fresh scales per hop. Returns
-    out[r] = rank r's reduced chunk r."""
-    W = len(chunks)
-    state = {r: quant._np_quantize(chunks[r][(r + 1) % W], qd, block)
-             for r in range(W)}
-    out = {}
-    for i in range(1, W):
-        nxt = {}
-        for r in range(W):
-            s, q = state[(r + 1) % W]
-            d = quant._np_dequant(s, q, block)
-            acc = NP_FUNC[func](chunks[r][(r + 1 + i) % W], d)
-            if i < W - 1:
-                nxt[r] = quant._np_quantize(acc, qd, block)
-            else:
-                out[r] = acc
-        state = nxt
-    return out
-
-
-def _oracle_ag(mine, qd, block):
-    """Reference block-scaled ring allgather: own chunk exact, remote
-    chunks carry exactly ONE quantization (relays forward bytes)."""
-    W = len(mine)
-    enc = {o: quant._np_quantize(mine[o], qd, block) for o in range(W)}
-    out = []
-    for r in range(W):
-        rows = [mine[o] if o == r
-                else quant._np_dequant(enc[o][0], enc[o][1], block)
-                for o in range(W)]
-        out.append(np.concatenate(rows))
-    return out
-
-
-def _oracle_allreduce(ins, func, qd, block):
-    W = len(ins)
-    n = ins[0].size
-    pad = (-n) % W
-    chunks = [np.concatenate([x, np.zeros(pad, np.float32)]).reshape(W, -1)
-              for x in ins]
-    mine = _oracle_rs(chunks, func, qd, block)
-    outs = _oracle_ag(mine, qd, block)
-    return [o[:n] for o in outs]
-
-
 @pytest.fixture(scope="module")
 def coll4():
-    from accl_tpu.parallel import MeshCollectives, cpu_mesh
-    return MeshCollectives(cpu_mesh(4), "rank")
+    from accl_tpu.parallel import MeshCollectives, make_mesh
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    return MeshCollectives(make_mesh((4,), ("rank",),
+                                     devices=jax.devices()[:4]), "rank")
 
 
 def _finite_inputs(w, n, seed):
@@ -245,7 +238,7 @@ def test_mesh_ring_allreduce_matches_oracle(coll4, qd, func):
     x = coll4.shard(ins)
     out = np.asarray(coll4.allreduce(x, func=func, algorithm="ring",
                                      wire_dtype=qd, qblock=block))
-    ref = _oracle_allreduce(ins, func, qd, block)
+    ref = quant.ring_allreduce_reference(ins, func, qd, block)
     for r in range(W):
         _assert_bit_identical(out[r], ref[r], f"allreduce rank {r}")
 
@@ -258,7 +251,8 @@ def test_mesh_ring_reduce_scatter_and_allgather_match_oracle(coll4):
         x, func=ReduceFunc.SUM, algorithm="ring", wire_dtype=qd,
         qblock=block))
     chunks = [r.reshape(W, n) for r in rows]
-    ref = _oracle_rs(chunks, ReduceFunc.SUM, qd, block)
+    ref = quant.ring_reduce_scatter_reference(chunks, ReduceFunc.SUM, qd,
+                                              block)
     for r in range(W):
         _assert_bit_identical(out[r], ref[r], f"reduce_scatter rank {r}")
 
@@ -266,7 +260,7 @@ def test_mesh_ring_reduce_scatter_and_allgather_match_oracle(coll4):
     agx = coll4.shard(mine)
     ag = np.asarray(coll4.allgather(agx, algorithm="ring", wire_dtype=qd,
                                     qblock=block))
-    agref = _oracle_ag(mine, qd, block)
+    agref = quant.ring_allgather_reference(mine, qd, block)
     for r in range(W):
         _assert_bit_identical(ag[r], agref[r], f"allgather rank {r}")
 
@@ -297,6 +291,8 @@ def test_device_ring_vs_emu_quantized_oracle():
     from accl_tpu.testing import emu_world, run_ranks
 
     W, count = 4, 513
+    if len(jax.devices()) < W:
+        pytest.skip(f"needs {W} devices")
     ins = _finite_inputs(W, count, 41)
 
     def body(a):

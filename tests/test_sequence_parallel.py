@@ -6,7 +6,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from accl_tpu.utils.compat import shard_map as _shard_map
 
 from accl_tpu.parallel import (cpu_mesh, ring_attention_sharded,
                                ulysses_attention_sharded, seq_to_heads,
@@ -65,7 +64,7 @@ def test_seq_head_reshard_roundtrip():
         y = seq_to_heads(x, "sp")
         return heads_to_seq(y, "sp")
 
-    out = jax.jit(_shard_map(f, mesh=mesh, in_specs=spec,
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=spec,
                                 out_specs=spec))(xs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))
 

@@ -511,8 +511,8 @@ def test_bcast_round_robin_selector_skips_tree(world):
 
 def test_tpu_world_real_chip():
     """Hardware tier: the driver API on the REAL TPU device (single-rank
-    world). Gated on ACCL_TEST_TPU=1 with a tpu backend — the CI marker
-    TPU_CI_r02.json records the last on-chip pass. Reference bar: the
+    world). Gated on ACCL_TEST_TPU=1 with a tpu backend — CHANGES.md
+    (PR 21) records the last on-chip pass. Reference bar: the
     hardware-tier tests (test/host/test_tcp_cmac_seq_mpi.py:29-443)."""
     import os
 
