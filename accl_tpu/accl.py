@@ -35,7 +35,7 @@ from .constants import (ACCLError, CCLOp, CfgFunc, CollectiveAlgorithm,
 from .device.base import Device
 from .log import get_logger
 from .retry import RetryPolicy, resolve_policy
-from .tracing import METRICS, Profiler, TRACE
+from .tracing import METRICS, Profiler, SPANS, TRACE, annotate
 
 log = get_logger(__name__)
 
@@ -122,6 +122,7 @@ class ACCL:
         self._barrier_buf: ACCLBuffer | None = None
         self._scratch_bufs: dict[tuple[int, str], ACCLBuffer] = {}
         self.profiler = Profiler()
+        self._holds_spans = False   # this driver keeps SPANS armed
         self.tuner = tuner
         # two-tier hierarchy (accl_tpu/hier): configured explicitly via
         # configure_hierarchy() or auto-derived once from a tuner's
@@ -670,10 +671,22 @@ class ACCL:
         the host-side recorder."""
         self._config_call(CfgFunc.start_profiling, 0)
         self.profiler.start()
+        self._hold_spans(True)
 
     def end_profiling(self):
         self._config_call(CfgFunc.end_profiling, 0)
         self.profiler.stop()
+        self._hold_spans(False)
+
+    def _hold_spans(self, on: bool):
+        """Arm the process's program spans (:data:`~accl_tpu.tracing.SPANS`)
+        while this driver profiles: they stay on while any driver does."""
+        if on != self._holds_spans:
+            self._holds_spans = on
+            if on:
+                SPANS.arm()
+            else:
+                SPANS.disarm()
 
     # -- observability (SURVEY §5: the ILA-probe/waveform-dump analogs) ----
     def start_trace(self):
@@ -739,6 +752,7 @@ class ACCL:
                 self.deregister_window(wid)
             except Exception:
                 pass
+        self._hold_spans(False)
         self.device.deinit()
 
     # -- buffers -----------------------------------------------------------
@@ -943,7 +957,20 @@ class ACCL:
         range stale traffic cannot satisfy; ``device.prepare_retry``
         purges the dead attempt's stranded rx frames; and the plan cache
         makes re-expansion free. Policies must be uniform across the
-        ranks of a communicator (docs/ARCHITECTURE.md, Failure model)."""
+        ranks of a communicator (docs/ARCHITECTURE.md, Failure model).
+
+        While spans are armed the call is the root span ``accl.call.<op>``:
+        it takes an id and re-enters here once, inside the span."""
+        if SPANS.enabled and not desc.span_call:
+            desc.span_call = SPANS.next_call()
+            cfg = desc.arithcfg
+            with annotate(f"accl.call.{desc.scenario.name}",
+                          call=desc.span_call, op=desc.scenario.name,
+                          nbytes=desc.count * (cfg.uncompressed_elem_bytes
+                                               if cfg is not None else 0),
+                          comm=desc.comm_id):
+                return self._call(desc, run_async, waitfor, chain,
+                                  retries, retry_policy)
         policy = resolve_policy(retries, retry_policy, self.retry_policy)
         if (policy is None or policy.retries <= 0
                 or desc.scenario == CCLOp.config):
@@ -1154,7 +1181,14 @@ class ACCL:
             handle.add_done_callback(_retired)
             return handle
         try:
-            handle.wait()
+            if SPANS.enabled and not handle.done():
+                # a member waiting for its peers and the launch; the
+                # launching rank and inline ops find theirs retired
+                with annotate("accl.wait", call=desc.span_call,
+                              group=desc.span_group):
+                    handle.wait()
+            else:
+                handle.wait()
         except ACCLError:
             METRICS.inc("accl_call_errors_total", op=op,
                         comm_id=desc.comm_id)

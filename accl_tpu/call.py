@@ -64,6 +64,13 @@ class CallDescriptor:
     # complete in submission order; a failed link aborts its successors.
     # Backends without cross-call pipelining ignore the hint.
     chain: bool = False
+    # Span identifiers (accl_tpu.tracing.SPANS), host-side only and set
+    # only while spans are armed: the per-process id every span of this
+    # call carries as ``call``, and the rendezvous slot
+    # ("<comm_id>:<index>") a collective deposited into, which the spans
+    # of every member of that collective carry as ``group``.
+    span_call: int = 0
+    span_group: str = ""
 
 
 class CallHandle:
@@ -102,8 +109,8 @@ class CallHandle:
 
     def add_done_callback(self, fn):
         """Invoke ``fn(error_word)`` when the call retires (immediately if
-        already retired). Used by the tracing subsystem to attribute true
-        device-side durations to async chained calls."""
+        already retired). Used by the tracing subsystem to attribute an
+        async chained call its issue -> retire host time."""
         with self._cb_lock:
             if not self._done.is_set():
                 self._callbacks.append(fn)

@@ -62,6 +62,7 @@ from ..parallel.collectives import MeshCollectives, _wire_name
 from ..parallel.mesh import make_mesh
 from ..parallel.tree import Tree2DCollectives
 from ..rma.window import WindowRegistry
+from ..tracing import SPANS, annotate, watch_lowerings
 from .base import Device
 
 log = get_logger(__name__)
@@ -75,6 +76,14 @@ def _noncanonical(dtype) -> bool:
     streamed-local ops, and the cross-rank send refusal."""
     d = np.dtype(dtype)
     return jax.dtypes.canonicalize_dtype(d) != d
+
+
+def _payload_bytes(desc: CallDescriptor, count: int | None = None) -> int:
+    """Uncompressed bytes of ``count`` (default: the call's) elements, for
+    a span's ``nbytes``."""
+    cfg = desc.arithcfg
+    n = desc.count if count is None else count
+    return n * cfg.uncompressed_elem_bytes if cfg is not None else 0
 
 
 def _factor_2d(w: int) -> tuple[int, int]:
@@ -127,6 +136,7 @@ class TpuContext:
     def __init__(self, world_size: int | None = None, mesh=None,
                  axis_name: str = "rank", platform: str | None = None,
                  algorithm: str = "xla"):
+        watch_lowerings()
         if mesh is None:
             mesh = make_mesh((world_size,) if world_size else None,
                              (axis_name,), platform=platform)
@@ -765,8 +775,20 @@ class TpuDevice(Device):
             handle.complete(int(ErrorCode.INVALID_CALL), exception=exc)
 
     # -- operand staging ---------------------------------------------------
+    # Host staging is spanned while SPANS is armed: accl.stage.read (the
+    # D2H read or host-mirror read), accl.stage.reduce (the numpy combine)
+    # and accl.stage.write (the landing, an H2D device_put for a
+    # device-resident buffer).
     def _read_operand(self, addr: int, count: int, desc, which: Compression
                       ) -> np.ndarray:
+        if SPANS.enabled:
+            with annotate("accl.stage.read", call=desc.span_call,
+                          nbytes=_payload_bytes(desc, count)):
+                return self._read_staged(addr, count, desc, which)
+        return self._read_staged(addr, count, desc, which)
+
+    def _read_staged(self, addr: int, count: int, desc, which: Compression
+                     ) -> np.ndarray:
         cfg = desc.arithcfg
         buf = self.dev_bufs.get(addr)
         if buf is not None:
@@ -786,6 +808,13 @@ class TpuDevice(Device):
             cfg.uncompressed_dtype, copy=False)
 
     def _write_result(self, addr: int, data: np.ndarray, desc):
+        if SPANS.enabled:
+            with annotate("accl.stage.write", call=desc.span_call,
+                          nbytes=np.asarray(data).nbytes):
+                return self._write_staged(addr, data, desc)
+        return self._write_staged(addr, data, desc)
+
+    def _write_staged(self, addr: int, data: np.ndarray, desc):
         cfg = desc.arithcfg
         out = (cfg.compressed_dtype
                if desc.compression & Compression.RES_COMPRESSED
@@ -868,8 +897,12 @@ class TpuDevice(Device):
                                    Compression.OP0_COMPRESSED)
             b = self._read_operand(desc.addr_1, desc.count, desc,
                                    Compression.OP1_COMPRESSED)
-            self._write_result(desc.addr_2, _REDUCERS[desc.function](a, b),
-                               desc)
+            if SPANS.enabled:
+                with annotate("accl.stage.reduce", call=desc.span_call):
+                    out = _REDUCERS[desc.function](a, b)
+            else:
+                out = _REDUCERS[desc.function](a, b)
+            self._write_result(desc.addr_2, out, desc)
             return 0
         if op == CCLOp.send:
             return self._do_send(desc, comm)
@@ -1234,13 +1267,47 @@ class TpuDevice(Device):
         which the context's deadline sweeper fails with
         RECEIVE_TIMEOUT_ERROR per member (the old per-waiter timeout
         semantics)."""
-        ctx = self.ctx
         # the deposit's parked lifetime is bounded by the CALLER's absolute
         # deadline when one was imposed (call_sync timeout plumbed via the
         # desc, measured from call_sync entry): a collective that timed out
         # for its caller must not be completed later by late-arriving peers
         deadline = (desc.deadline if desc.deadline is not None
                     else time.monotonic() + self.timeout)
+        if SPANS.enabled:
+            with annotate("accl.deposit", call=desc.span_call) as span:
+                key, group, expired, is_last = self._deposit(
+                    desc, comm, handle, deadline)
+                desc.span_group = f"{key[0]}:{key[1]}"
+                span.set(group=desc.span_group)
+        else:
+            key, group, expired, is_last = self._deposit(
+                desc, comm, handle, deadline)
+        for h in expired:
+            h.complete(int(ErrorCode.RECEIVE_TIMEOUT_ERROR),
+                       exception=ACCLError(
+                           int(ErrorCode.RECEIVE_TIMEOUT_ERROR),
+                           "collective member deadline expired"))
+        if not is_last:
+            # the synchronous-call path (call_sync/_run_one's caller)
+            # blocks in handle.wait(); async callers hold the handle
+            return None
+        if defer_launch:
+            # async last arrival: the heavy launch must not run in the
+            # submitter's thread (call_async would block for the whole
+            # collective) — hop it to this rank's worker. The inflight
+            # slot keeps later same-rank calls FIFO behind it.
+            self._inflight_add()
+            self._calls.put(lambda: self._finish_group(group, comm))
+            return None
+        self._finish_group(group, comm)
+        return None
+
+    def _deposit(self, desc: CallDescriptor, comm: Communicator,
+                 handle: CallHandle, deadline: float):
+        """Deposit into the rendezvous slot under the ctx lock: (key,
+        group, expired members' handles, whether this arrival completed
+        and claimed the group)."""
+        ctx = self.ctx
         with ctx._lock:
             # index assignment under the ctx lock: deposit order IS the
             # per-rank matching order (MPI program-order matching)
@@ -1268,28 +1335,22 @@ class TpuDevice(Device):
                 del ctx._pending[key]
             else:
                 ctx._ensure_sweeper()
-        for h in expired:
-            h.complete(int(ErrorCode.RECEIVE_TIMEOUT_ERROR),
-                       exception=ACCLError(
-                           int(ErrorCode.RECEIVE_TIMEOUT_ERROR),
-                           "collective member deadline expired"))
-        if not is_last:
-            # the synchronous-call path (call_sync/_run_one's caller)
-            # blocks in handle.wait(); async callers hold the handle
-            return None
-        if defer_launch:
-            # async last arrival: the heavy launch must not run in the
-            # submitter's thread (call_async would block for the whole
-            # collective) — hop it to this rank's worker. The inflight
-            # slot keeps later same-rank calls FIFO behind it.
-            self._inflight_add()
-            self._calls.put(lambda: self._finish_group(group, comm))
-            return None
-        self._finish_group(group, comm)
-        return None
+        return key, group, expired, is_last
 
     def _finish_group(self, group: dict, comm: Communicator) -> None:
-        """Launch a claimed group and complete EVERY member's handle."""
+        """Launch a claimed group and complete EVERY member's handle;
+        while spans are armed, inside ``accl.launch.<op>`` on the
+        launching rank's thread."""
+        if SPANS.enabled:
+            d = group[comm.local_rank][0]
+            with annotate(f"accl.launch.{d.scenario.name}",
+                          call=d.span_call, group=d.span_group,
+                          nbytes=_payload_bytes(d)):
+                self._complete_group(group, comm)
+        else:
+            self._complete_group(group, comm)
+
+    def _complete_group(self, group: dict, comm: Communicator) -> None:
         err = int(ErrorCode.INVALID_CALL)
         exc_out: BaseException | None = None
         try:
@@ -1520,11 +1581,28 @@ class TpuDevice(Device):
             dsts.append(db)
         func = (d0.function if op in (CCLOp.allreduce, CCLOp.reduce_scatter)
                 else ReduceFunc.SUM)
-        x = self.ctx.assemble_flat(coll, srcs)
-        out = coll._program_flat(op.name, alg, func, _wire_name(wire),
-                                 None, qblock)(x)
-        self._rebind_out_shards(coll, out, dict(enumerate(dsts)), devs)
+        self._run_flat(coll, srcs, coll._program_flat(
+            op.name, alg, func, _wire_name(wire), None, qblock),
+            dict(enumerate(dsts)), devs, descs)
         return 0
+
+    def _run_flat(self, coll, srcs: list, program, dst_map: dict, devs,
+                  descs) -> None:
+        """The device-resident launch: assemble the flat operand from the
+        ranks' arrays, dispatch ``program`` on it, rebind the result
+        shards — spanned accl.assemble / accl.dispatch / accl.rebind
+        while SPANS is armed."""
+        if not SPANS.enabled:
+            out = program(self.ctx.assemble_flat(coll, srcs))
+            self._rebind_out_shards(coll, out, dst_map, devs)
+            return
+        call = descs[devs.index(self)].span_call
+        with annotate("accl.assemble", call=call):
+            x = self.ctx.assemble_flat(coll, srcs)
+        with annotate("accl.dispatch", call=call):
+            out = program(x)
+        with annotate("accl.rebind", call=call):
+            self._rebind_out_shards(coll, out, dst_map, devs)
 
     def _rebind_out_shards(self, coll, out, dst_map: dict, devs):
         """Rebind a flat program output's per-rank shards onto the
@@ -1640,11 +1718,9 @@ class TpuDevice(Device):
         else:
             return None
 
-        x = self.ctx.assemble_flat(coll, srcs)
         func = d0.function if op == CCLOp.reduce else ReduceFunc.SUM
-        out = coll._program_flat(op.name, alg, func, _wire_name(wire),
-                                 root)(x)
-        self._rebind_out_shards(coll, out, dst_map, devs)
+        self._run_flat(coll, srcs, coll._program_flat(
+            op.name, alg, func, _wire_name(wire), root), dst_map, devs, descs)
         return 0
 
 

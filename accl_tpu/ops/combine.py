@@ -82,6 +82,7 @@ def combine_pallas(a: jax.Array, b: jax.Array,
         # ~700 to ~400 GB/s; XLA inserts a defensive copy when the caller
         # still holds op0, so semantics stay functional.
         input_output_aliases={0: 0},
+        name="accl_combine",
         interpret=_interpret(),
     )(a, b)
 

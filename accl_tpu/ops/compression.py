@@ -68,6 +68,7 @@ def _cast_tiles(x: jax.Array, dtype) -> jax.Array:
                                memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(block, lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
+        name="accl_cast",
         interpret=_interpret(),
     )(x)
 
@@ -159,6 +160,7 @@ def compress_fp8(x: jax.Array, wire_dtype=FP8
         ],
         out_specs=pl.BlockSpec(block, lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
+        name="accl_fp8_quant",
         interpret=_interpret(),
     )(tiles.astype(jnp.float32), inv)
     return q.reshape(-1)[:n].reshape(x.shape), scale.reshape(1, 1)
@@ -180,6 +182,7 @@ def decompress_fp8(q: jax.Array, scale: jax.Array,
         ],
         out_specs=pl.BlockSpec(block, lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
+        name="accl_fp8_dequant",
         interpret=_interpret(),
     )(tiles, scale.reshape(1, 1).astype(jnp.float32))
     return _untiled(out, n, q.shape)
@@ -434,6 +437,7 @@ def _bs_quant_call(tiles: jax.Array, one: jax.Array, qmax: jax.Array,
                                 memory_space=pltpu.VMEM),
                    pl.BlockSpec((R, 1), lambda i: (i, 0),
                                 memory_space=pltpu.VMEM)),
+        name="accl_bs_quant",
         interpret=_interpret(),
     )(tiles, one, qmax)
 
@@ -457,6 +461,7 @@ def _bs_dequant_call(qtiles: jax.Array, scales: jax.Array, block: int):
                                memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((R, block), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
+        name="accl_bs_dequant",
         interpret=_interpret(),
     )(qtiles, scales)
 
@@ -510,6 +515,8 @@ def _bs_combine_call(qtiles: jax.Array, scales: jax.Array,
         grid=(pl.cdiv(rows, R),),
         in_specs=[row_spec, s_spec, row_spec, smem, smem],
         out_specs=out_specs,
+        name=("accl_bs_combine_requant" if requant
+              else "accl_bs_combine"),
         interpret=_interpret(),
     )(qtiles, scales, other, one, qmax)
 

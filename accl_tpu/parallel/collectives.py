@@ -492,16 +492,23 @@ class MeshCollectives:
                     x[0], ax, wdt, qblock, (one, qmax)).reshape(-1)[None]
         return f
 
+    @staticmethod
+    def _resolved(op: str, algorithm: str, func: ReduceFunc) -> str:
+        """The algorithm a plain (not block-scaled) program runs: XLA has
+        no fused product-reduce collective, so such a reduction takes the
+        ring path."""
+        if func not in _PSUM_LIKE and algorithm == "xla" and op in (
+                "allreduce", "reduce", "reduce_scatter"):
+            return "ring"
+        return algorithm
+
     def _shard_fn(self, op: str, algorithm: str, func: ReduceFunc,
                   wire: str | None, root: int | None) -> Callable:
         """Build the per-shard body f: (1, n_in) -> (1, n_out) shared by
         the stacked (W, n) and flat (W*n,) program layouts."""
         ax = self.axis_name
         wire_dtype = jnp.dtype(wire) if wire else None
-        # XLA has no fused product-reduce collective; use the ring path
-        if func not in _PSUM_LIKE and algorithm == "xla" and op in (
-                "allreduce", "reduce", "reduce_scatter"):
-            algorithm = "ring"
+        algorithm = self._resolved(op, algorithm, func)
         if op == "reduce" and algorithm == "ring":
             def f(x):
                 r = ring_allreduce_shard(x[0], ax, func, wire_dtype)
@@ -629,14 +636,16 @@ class MeshCollectives:
         if self._bs_eligible(op, wire, qblock):
             # check_vma off: shard_map has no replication rule for
             # pallas_call; every bs program output is rank-varying anyway
-            f = self._bs_shard_fn(op, func, wire, qblock)
+            f = _named(self._bs_shard_fn(op, func, wire, qblock),
+                       op, "ring", wire, "bs")
             fn = jax.shard_map(f, mesh=self.mesh,
                                in_specs=(P(ax, None), P(None, None),
                                          P(None, None)),
                                out_specs=P(ax, None), check_vma=False)
             prog = self._cache[ck] = self._bs_wrap(fn, wire)
             return prog
-        f = self._shard_fn(op, algorithm, func, wire, root)
+        f = _named(self._shard_fn(op, algorithm, func, wire, root),
+                   op, self._resolved(op, algorithm, func), wire)
         fn = jax.shard_map(f, mesh=self.mesh, in_specs=P(ax, None),
                            out_specs=P(ax, None))
         prog = self._cache[ck] = jax.jit(fn)
@@ -661,7 +670,8 @@ class MeshCollectives:
             def g(x, one, qmax):
                 return f(x[None], one, qmax)[0]
 
-            fn = jax.shard_map(g, mesh=self.mesh,
+            fn = jax.shard_map(_named(g, op, "ring", wire, "bs"),
+                               mesh=self.mesh,
                                in_specs=(P(ax), P(None, None), P(None, None)),
                                out_specs=P(ax), check_vma=False)
             prog = self._cache[ck] = self._bs_wrap(fn, wire)
@@ -671,8 +681,9 @@ class MeshCollectives:
         def g(x):
             return f(x[None])[0]
 
-        fn = jax.shard_map(g, mesh=self.mesh, in_specs=P(ax),
-                           out_specs=P(ax))
+        fn = jax.shard_map(_named(g, op, self._resolved(op, algorithm, func),
+                                  wire),
+                           mesh=self.mesh, in_specs=P(ax), out_specs=P(ax))
         prog = self._cache[ck] = jax.jit(fn)
         return prog
 
@@ -733,8 +744,8 @@ class MeshCollectives:
         def f(x):
             return send_recv(x[0], list(pairs), ax)[None]
 
-        fn = jax.shard_map(f, mesh=self.mesh, in_specs=P(ax, None),
-                           out_specs=P(ax, None))
+        fn = jax.shard_map(_named(f, "exchange"), mesh=self.mesh,
+                           in_specs=P(ax, None), out_specs=P(ax, None))
         self._evict_exchange_programs()
         prog = self._cache[ck] = jax.jit(fn)
         return prog
@@ -770,8 +781,8 @@ class MeshCollectives:
         def g(x):
             return send_recv(x, list(pairs), ax)
 
-        fn = jax.shard_map(g, mesh=self.mesh, in_specs=P(ax),
-                           out_specs=P(ax))
+        fn = jax.shard_map(_named(g, "exchange"), mesh=self.mesh,
+                           in_specs=P(ax), out_specs=P(ax))
         self._evict_exchange_programs()
         prog = self._cache[ck] = jax.jit(fn)
         return prog
@@ -785,3 +796,14 @@ class MeshCollectives:
 
 def _wire_name(wire_dtype) -> str | None:
     return None if wire_dtype is None else jnp.dtype(wire_dtype).name
+
+
+def _named(fn: Callable, *parts) -> Callable:
+    """Give a program's function the stable name ``accl_<part>_...`` (parts
+    that are None left out) before it is jitted: the trace's ``XLA
+    Modules`` line then shows ``jit_accl_allreduce_xla``. The algorithm
+    part is the one the program runs (:meth:`MeshCollectives._resolved`;
+    the block-scaled lane is always a ring)."""
+    fn.__name__ = fn.__qualname__ = "_".join(
+        ["accl", *(str(p) for p in parts if p is not None)])
+    return fn

@@ -11,18 +11,21 @@ first-class software replacements for the TPU rebuild; this module is it:
 * :class:`Profiler` — per-call timing records captured at handle-retire
   time, with per-op summary statistics (count/total/mean/p50/p95) and CSV
   export in the reference benchmark's spirit.
-* :func:`annotate` — names a region in the JAX/XLA profiler timeline
-  (``jax.profiler.TraceAnnotation``), the TPU-native analog of dropping an
-  ILA probe on a subsystem.
-* :func:`trace_to` — capture an xplane trace directory
-  (``jax.profiler.start_trace``), the analog of a waveform dump
-  (test/simulation/cclo.wcfg).
+* :func:`annotate` and :data:`SPANS` — the program's own spans
+  (``accl.*``: the driver's call, the TPU tier's rendezvous, launch and
+  host staging), written as ``jax.profiler.TraceAnnotation`` events on
+  the device trace's clock and kept in memory for the last profiled
+  interval. Armed while any driver profiles; a disarmed span site is one
+  attribute test. :func:`watch_lowerings` counts JAX lowerings into
+  ``jax_lowerings_total``.
 * :func:`measure_call_latency` — the ``nop`` latency probe, returning the
   same p50-style microsecond figure the reference benchmark derives.
 
 Records are captured when the backend retires the call (the handle's done
-callback), so async chains are attributed their true device-side duration,
-not the host's dispatch time.
+callback), so an async chain is attributed its issue -> retire time, not
+only the host's dispatch. A record is host time: on the TPU tier a
+device-resident collective retires once its program is enqueued, before
+the device has run it.
 
 Two further surfaces (PR 6) make the dataplane itself observable:
 
@@ -54,7 +57,6 @@ The process-wide singletons are ``TRACE`` and ``METRICS``.
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import itertools
 import json
@@ -65,8 +67,9 @@ import weakref
 
 __all__ = [
     "CallRecord", "Profiler", "ProfilerSummary", "EventTrace",
-    "MetricsRegistry", "TRACE", "METRICS", "annotate", "trace_to",
-    "measure_call_latency",
+    "MetricsRegistry", "SpanLog", "TRACE", "METRICS", "SPANS",
+    "SPAN_CAPACITY", "annotate",
+    "watch_lowerings", "measure_call_latency",
 ]
 
 @dataclasses.dataclass
@@ -161,7 +164,9 @@ class Profiler:
 
     The driver owns one and feeds it from call-handle done callbacks while
     enabled (``ACCL.start_profiling`` / ``end_profiling``). It can also be
-    used standalone via :meth:`record`.
+    used standalone via :meth:`record`. A record is host time from issue
+    to retire: on the TPU tier a device-resident collective retires once
+    its program is enqueued, not when the device has run it.
     """
 
     def __init__(self):
@@ -686,6 +691,10 @@ class MetricsRegistry:
         with self._lock:
             self._counters[key] = self._counters.get(key, 0) + value
 
+    def counter(self, name: str, **labels) -> float:
+        """A directly-written counter's value (0 before its first write)."""
+        return self._counters.get(self._key(name, labels), 0)
+
     def set_gauge(self, name: str, value: float, **labels):
         with self._lock:
             self._gauges[self._key(name, labels)] = value
@@ -823,34 +832,168 @@ class MetricsRegistry:
             self._hists.clear()
 
 
+# -- program spans on the device trace's clock --------------------------------
+
+# Entries SPANS keeps for one armed interval. A traced 20 s window of the
+# busiest chip benchmark cell records ~0.2M; past this many a span is
+# counted in SPANS.dropped and not kept.
+SPAN_CAPACITY = 1 << 19
+
+
+class _Span:
+    """One armed span: a ``TraceAnnotation`` (the profiler's trace, on the
+    device's clock) and, at exit, an entry in :data:`SPANS`. The entry's
+    start is taken before the annotation is built and its end after the
+    annotation has closed, so a span's own cost lies inside it and not in
+    its parent's self time."""
+
+    __slots__ = ("_tm", "_t0", "name", "args")
+
+    def __init__(self, name: str, args: dict):
+        self._t0 = time.perf_counter_ns()
+        self.name, self.args = name, args
+        self._tm = SPANS.annotation(name, **args)
+
+    def set(self, **args):
+        """Metadata known only inside the span (a rendezvous slot)."""
+        self.args.update(args)
+        self._tm.set_metadata(**args)
+
+    def __enter__(self):
+        self._tm.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._tm.__exit__(*exc)
+        SPANS.add(self.name, self._t0, time.perf_counter_ns(), self.args)
+
+
+class _NoSpan:
+    """What :func:`annotate` returns while spans are off."""
+
+    def set(self, **args):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class SpanLog:
+    """The program's spans (``accl.*``), armed while any driver profiles
+    (``ACCL.start_profiling`` .. ``end_profiling``, counted across
+    drivers: :meth:`arm` / :meth:`disarm`).
+
+    A span site tests ``SPANS.enabled`` and only then calls
+    :func:`annotate`, so a disarmed site builds nothing. An armed span is
+    a ``jax.profiler.TraceAnnotation`` — its args are the event's
+    metadata, on the clock of the device ops when a profiler trace is
+    being taken — and an entry ``(name, thread, t0_ns, t1_ns, args)``
+    kept in memory until the next arming, so a reader in this process
+    gets the last profiled interval without a trace file. The first
+    :data:`SPAN_CAPACITY` entries of an interval are kept; ``dropped``
+    counts those past it. Spans of one call share ``call``; the spans of
+    one collective on every member's thread share ``group``.
+    ``lowerings`` is the growth of ``jax_lowerings_total`` over the last
+    armed interval (None while armed, or before the first)."""
+
+    def __init__(self):
+        self.enabled = False
+        self.lowerings: int | None = None
+        self.dropped = 0
+        self.annotation = None      # jax.profiler.TraceAnnotation, once armed
+        self._holders = 0
+        self._lowered0 = 0.0
+        self._lock = threading.Lock()
+        self._spans: list = []
+        self._calls = itertools.count(1)
+
+    def arm(self):
+        with self._lock:
+            self._holders += 1
+            if self._holders > 1:
+                return
+            if self.annotation is None:
+                from jax.profiler import TraceAnnotation
+                self.annotation = TraceAnnotation
+            self._spans = []
+            self.dropped = 0
+            self.lowerings = None
+            self._lowered0 = METRICS.counter("jax_lowerings_total")
+            self.enabled = True
+
+    def disarm(self):
+        with self._lock:
+            if self._holders == 0:
+                return
+            self._holders -= 1
+            if self._holders == 0:
+                self.enabled = False
+                self.lowerings = int(METRICS.counter("jax_lowerings_total")
+                                     - self._lowered0)
+
+    def next_call(self) -> int:
+        """A per-process call id (``itertools.count``: concurrent rank
+        threads never share one)."""
+        return next(self._calls)
+
+    def add(self, name: str, t0_ns: int, t1_ns: int, args: dict):
+        if len(self._spans) < SPAN_CAPACITY:
+            self._spans.append((name, threading.get_ident(), t0_ns, t1_ns,
+                                args))
+        else:
+            self.dropped += 1
+
+    def spans(self) -> list[tuple]:
+        """``(name, thread, t0_ns, t1_ns, args)`` of the last armed
+        interval, in order of their end (``time.perf_counter_ns``)."""
+        return list(self._spans)
+
+
 # Process-wide singletons: the whole point is ONE health surface across
 # every world/daemon living in this process.
 TRACE = EventTrace()
 METRICS = MetricsRegistry()
+SPANS = SpanLog()
 
 
-# -- JAX profiler bridges ---------------------------------------------------
-@contextlib.contextmanager
-def annotate(name: str):
-    """Name a region on the device timeline (xplane trace annotation)."""
-    try:
-        import jax
-        ctx = jax.profiler.TraceAnnotation(name)
-    except ImportError:  # pragma: no cover — jax is baked in
-        ctx = contextlib.nullcontext()
-    with ctx:
-        yield
+def annotate(name: str, **args):
+    """A span named ``name`` whose ``args`` become its trace metadata (on
+    a CPU trace they read back as ``event.stats``); a no-op context while
+    :data:`SPANS` is disarmed. The one way the program emits a span."""
+    if not SPANS.enabled:
+        return _NO_SPAN
+    return _Span(name, args)
 
-@contextlib.contextmanager
-def trace_to(log_dir: str):
-    """Capture an xplane trace of the enclosed region into ``log_dir``."""
-    import jax
 
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+_LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_lowerings_lock = threading.Lock()
+_lowerings_watched = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kwargs):
+    if event == _LOWERING_EVENT:
+        METRICS.inc("jax_lowerings_total")
+
+
+def watch_lowerings():
+    """Count every JAX lowering from now on — a jit cache miss, whether or
+    not the persistent cache then supplies the executable — into
+    ``jax_lowerings_total``. Idempotent; the TPU tier calls it when a
+    world is built, so a warm window reads 0."""
+    global _lowerings_watched
+    with _lowerings_lock:
+        if _lowerings_watched:
+            return
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _lowerings_watched = True
+
 
 def measure_call_latency(accl, n: int = 100) -> dict[str, float]:
     """Round-trip latency of the full call path via ``nop``.
