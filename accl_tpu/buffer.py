@@ -109,6 +109,11 @@ class ACCLBuffer:
         self._dtype = np.dtype(arr.dtype)
         self._size = math.prod(self._shape)
 
+    def _swap(self, arr):
+        """:meth:`_rebind` for an array the backend has proven to have
+        this buffer's shape and dtype: only the array changes."""
+        self._jax = arr
+
     # -- numpy-ish surface -------------------------------------------------
     @property
     def data(self) -> np.ndarray:

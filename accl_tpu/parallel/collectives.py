@@ -655,7 +655,7 @@ class MeshCollectives:
                       wire: str | None, root: int | None, qblock: int = 0):
         """Flat layout: global (W*n,) arrays whose per-device shards are
         rank-local 1-D operands. This is the device-resident buffer path:
-        shards assembled with jax.make_array_from_single_device_arrays
+        shards assembled into the flat global (TpuContext.assemble_flat)
         keep their (n,) shape, so no per-shard host reshape is needed on
         either side of the call (the [None]/[0] axis plumbing is free
         inside the jitted program)."""

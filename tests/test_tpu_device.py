@@ -307,6 +307,9 @@ def test_disjoint_comms_execute_concurrently():
         def __init__(self, inner):
             self._inner = inner
 
+        def __getattr__(self, name):   # the launch's plan store, etc.
+            return getattr(self._inner, name)
+
         def shard(self, rows):
             return self._inner.shard(rows)
 
