@@ -7,11 +7,12 @@ made on the chips from the seed, and a warm-up that runs every program
 shape the window will use. The window then runs for ``--seconds`` and
 closes at the end of the step in flight. With ``--trace 0`` the line
 carries the cell's end-to-end metrics; with ``--trace 1`` the window runs
-under the profiler and the driver's call profiler, and the line carries
-the per-layer metrics. Each metric is read by ``metrics/<name>.py``. The
-program's results are compared with the plain reference (``check.py``)
-after the window, once the program's buffers are freed; each number
-compared is printed with its limit, last on stderr and last in the line.
+under the profiler and the driver's call profiler, the program's launch-plan
+counter is read at its start and end, and the line carries the per-layer
+metrics. Each metric is read by ``metrics/<name>.py``. The program's
+results are compared with the plain reference (``check.py``) after the
+window, once the program's buffers are freed; each number compared is
+printed with its limit, last on stderr and last in the line.
 """
 
 from __future__ import annotations
@@ -45,6 +46,7 @@ class Reading:
     peak: dict              # peaks.PEAKS row of the device kind
     records: list | None    # traced: every rank's driver CallRecords
     trace: object | None    # traced: trace_reduce.Reduction of the window
+    plan_launches: dict | None = None   # traced: launches by plan result
 
     def median_call_us(self) -> float | None:
         durs = [r.duration_us for r in self.records or ()]
@@ -54,6 +56,11 @@ class Reading:
         if self.trace is None:
             return None
         return 100.0 * (1.0 - self.trace.busy_s / self.trace.window_s)
+
+    def plan_hit_percent(self) -> float | None:
+        got = self.plan_launches or {}
+        total = sum(got.get(k, 0) for k in ("hit", "miss", "fallback"))
+        return 100.0 * got.get("hit", 0) / total if total > 0 else None
 
 
 def _parse(argv):
@@ -86,6 +93,16 @@ def compile_cache():
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def plan_launches(since: dict | None = None) -> dict:
+    """The program's collective launches by launch-plan result
+    (``tpu_launch_plan_total{result}``: hit, miss, fallback), so far or
+    since the reading ``since``; empty where the program counts none."""
+    from accl_tpu.tracing import METRICS
+    got = METRICS.snapshot()["counters"].get("tpu_launch_plan_total", {})
+    now = {k.split("=", 1)[1]: v for k, v in got.items()}
+    return {k: v - (since or {}).get(k, 0) for k, v in now.items()}
 
 
 def _cpu_s() -> float:
@@ -123,7 +140,9 @@ def main(argv=None, t_start: float | None = None) -> int:
     traced = bool(args.trace)
     span = drive.spans(traced)
     trace_dir = TRACE_DIR / args.workload
+    plans = None
     if traced:
+        plans = plan_launches()
         shutil.rmtree(trace_dir, ignore_errors=True)
         for a in accls:
             a.profiler.clear()
@@ -139,6 +158,8 @@ def main(argv=None, t_start: float | None = None) -> int:
         if traced:
             jax.profiler.stop_trace()
     cpu_s = _cpu_s() - cpu0
+    if traced:
+        plans = plan_launches(since=plans)
     failed = r.failed
     attempted = win.calls * len(accls)
     records = None
@@ -169,7 +190,7 @@ def main(argv=None, t_start: float | None = None) -> int:
         device.update(busy_s=red.busy_s, window_s=red.window_s)
     reading = Reading(cell=cell, setup_s=setup_s, window=win,
                       peak=peaks.peak_for(d0.device_kind), records=records,
-                      trace=red)
+                      trace=red, plan_launches=plans)
     metrics = {}
     for m in (cell.per_layer if traced else cell.end_to_end):
         value = spec.reader(m["name"])(reading)

@@ -344,6 +344,22 @@ def test_per_layer_readers_on_synthetic_timings():
     assert spec.reader("coll_roofline")(untraced) is None
 
 
+@pytest.mark.parametrize("name,cell", [("plan_hit_share.bucket", "ddp25.f32"),
+                                       ("plan_hit_share.small",
+                                        "acclbench.local")])
+def test_plan_hit_share_on_synthetic_counts(name, cell):
+    share = spec.reader(name)
+    cell = spec.find_cell(cell)
+
+    def read(counts):
+        return share(run.Reading(cell, 1.0, _window(), {}, None, None,
+                                 plan_launches=counts))
+    assert read({"hit": 192, "miss": 0, "fallback": 0}) == 100.0
+    assert read({"hit": 6, "miss": 1, "fallback": 1}) == 75.0
+    assert read({"hit": 0, "miss": 0, "fallback": 0}) is None
+    assert read({}) is None and read(None) is None
+
+
 def test_unknown_device_kind_raises():
     with pytest.raises(KeyError, match="no peaks"):
         peaks.peak_for("TPU v9 imaginary")

@@ -24,9 +24,11 @@ SEED = 2**33 + 29
 TINY = {"ddp25.f32": {"parameters": 3000, "bucket_cap_mb": 0.004},
         "acclbench.local": {"sizes_bytes": [8, 800, 8000]}}
 NEW = {"ddp25.f32": ("launch_us.bucket", "wait_us.bucket",
-                     "call_self_us.bucket", "lowerings.bucket"),
+                     "call_self_us.bucket", "plan_hit_share.bucket",
+                     "lowerings.bucket"),
        "acclbench.local": ("stage_us.small", "launch_us.small",
-                           "call_self_us.small", "lowerings.small")}
+                           "call_self_us.small", "plan_hit_share.small",
+                           "lowerings.small")}
 
 
 def tiny(name: str) -> spec.Cell:
@@ -41,12 +43,14 @@ def read_all(cell: spec.Cell, reading) -> dict:
 @pytest.mark.parametrize("name", sorted(NEW))
 def test_span_readers_read_a_profiled_window(name):
     """A profiled window, as a traced run profiles it: every span metric
-    reads a number, and a warmed-up window lowers nothing."""
+    reads a number, a warmed-up window lowers nothing, and every launch in
+    it runs from its plan."""
     cell = tiny(name)
     accls = tpu_world(cell.chips)
     try:
         r = drive.Run(accls, cell, SEED)
         r.warm()
+        plans = run.plan_launches()
         for a in accls:
             a.start_profiling()
         try:
@@ -54,11 +58,13 @@ def test_span_readers_read_a_profiled_window(name):
         finally:
             for a in accls:
                 a.end_profiling()
+        plans = run.plan_launches(since=plans)
         r.results()
     finally:
         for a in accls:
             a.deinit()
-    got = read_all(cell, run.Reading(cell, 1.0, win, {}, None, None))
+    got = read_all(cell, run.Reading(cell, 1.0, win, {}, None, None,
+                                     plan_launches=plans))
     assert all(v is not None for v in got.values()), got
     assert got[NEW[name][-1]] == 0
     assert all(v > 0 for k, v in got.items() if not k.startswith("lower"))
@@ -69,6 +75,9 @@ def test_span_readers_read_a_profiled_window(name):
     launches = s.named("accl.launch.")
     assert len(launches) == sum(c for (op, _), c in win.issued.items()
                                 if op == "allreduce")
+    assert plans.get("hit") == len(launches)
+    assert not plans.get("miss") and not plans.get("fallback")
+    assert got[NEW[name][-2]] == 100.0
     waits = s.named("accl.wait")
     if name == "ddp25.f32":
         assert 0 < len(waits) <= 3 * len(launches)
