@@ -4,8 +4,9 @@
 runs one cell of ``BENCHMARK.json`` (``run.py``). Each cell is a
 configuration (``configs/``, its buffers cut by ``buffers/<rule>.py``) under
 a traffic mix (``traffic/``, data read by ``traffic.py``); each library call
-a mix names is issued by ``ops/<op>.py``, and each metric has a reader of its
-own (``metrics/``); ``spec.py`` finds them all by name. ``data.py`` makes
+a mix names is issued by ``ops/<op>.py``, and each metric is read by
+``metrics/<name>.py`` or, for ``<base>.<tag>``, ``metrics/<base>.py``;
+``spec.py`` finds them all by name. ``data.py`` makes
 the seeded payloads, ``drive.py`` drives them through the library,
 ``check.py`` holds the plain reference, ``peaks.py`` the peaks table and work
 counts, ``trace_reduce.py`` the reduction of a profiler trace,

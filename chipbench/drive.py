@@ -1,11 +1,16 @@
 """Drive a cell's traffic through the library's normal path.
 
 Every cell runs ``tpu_world(chips)``: one ``ACCL`` driver per rank, one
-thread per rank (as ``accl_tpu.testing.run_ranks`` does), device-resident
-buffers made on each rank's chip from the seed, and the synchronous call
-API. Each call is issued by its ``ops/<op>.py``. The results of the sampled
-calls are kept for the comparison with the plain reference (``check.py``),
-which runs after the window.
+thread per rank (as ``accl_tpu.testing.run_ranks`` does), buffers made from
+the seed, and the synchronous call API. The traffic's ``placement`` key
+says where the buffers live: ``"device"`` (the default), device-resident
+arrays made on each rank's chip; ``"host"``, host-mirror buffers made from
+numpy arrays, the driver's default buffer mode, whose calls stage through
+the host. Each call is issued by its ``ops/<op>.py``. The results of the
+sampled calls are kept for the comparison with the plain reference
+(``check.py``), which runs after the window; a host result is copied out
+of its buffer only where it is kept, and the last step's after the window
+has closed.
 """
 
 from __future__ import annotations
@@ -28,6 +33,7 @@ from . import data, spec
 from . import traffic as tr
 
 BARRIER_S = 600.0
+PLACEMENTS = ("device", "host")  # where a traffic's buffers live
 
 
 def _no_span(name):
@@ -50,9 +56,15 @@ def _make(keys, sizes, dt):
     return made + (tuple(jnp.zeros(n, dt) for n in sizes),)
 
 
-def make_rows(a, seed: int, sets: int, sizes, dt) -> list:
+def make_rows(a, seed: int, sets: int, sizes, dt, host: bool = False
+              ) -> list:
     """Input sets ``0 .. sets-1`` of buffers sized ``sizes`` for rank
-    ``a``, then a row of zeros, as device arrays."""
+    ``a``, then a row of zeros: device arrays, or numpy arrays where
+    ``host``."""
+    if host:
+        return [[data.values(np, data.stream_key(seed, s, a.rank, i), n, dt)
+                 for i, n in enumerate(sizes)] for s in range(sets)] + [
+            [np.zeros(n, dt) for n in sizes]]
     keys = np.array([[data.stream_key(seed, s, a.rank, i)
                       for i in range(len(sizes))] for s in range(sets)],
                     np.uint32).reshape(sets, len(sizes))
@@ -80,6 +92,10 @@ class Run:
         self.accls = accls
         self.traffic = cell.traffic
         self.dt = data.dtype(cell.config["dtype"])
+        where = cell.traffic.get("placement", "device")
+        if where not in PLACEMENTS:
+            raise ValueError(f"placement {where!r}: one of {PLACEMENTS}")
+        self.host = where == "host"
         self.sizes = tr.sizes(cell.config)
         self.plan = tr.plan(cell.traffic, len(self.sizes), seed)
         self.rows = self.plan.rows()
@@ -93,7 +109,7 @@ class Run:
         self.ins, self.outs = [], []
         for a in accls:
             made = make_rows(a, seed, int(cell.traffic["operand_sets"]),
-                             self.sizes, self.dt)
+                             self.sizes, self.dt, self.host)
             self.ins.append([[a.buffer(data=x) for x in row]
                              for row in made[:-1]])
             self.outs.append([a.buffer(data=z) for z in made[-1]])
@@ -173,9 +189,13 @@ class Run:
                             dst = self._issue(a, call, span)
                             if dst is None:
                                 continue
-                            dsts.append(dst)
                             if sample and call[3]:
-                                got.append(((k, c), dst.jax))
+                                keep = flagged + len(got) < self._keep
+                                got.append(((k, c), dst, self._result(dst)
+                                            if keep or not self.host
+                                            else None))
+                            if not self.host:   # a host result is in place
+                                dsts.append(dst)
                         with span("chipbench.wait"):
                             for d in dsts:
                                 d.jax.block_until_ready()
@@ -184,17 +204,27 @@ class Run:
                     with self._failed_lock:
                         self.failed += 1
                     self.abort = True
-                for key, arr in got[:max(self._keep - flagged, 0)]:
+                for key, _, arr in got[:max(self._keep - flagged, 0)]:
                     self._kept(key)[a.rank] = arr
                 flagged += len(got)
                 last = got
                 barrier.wait()
-            for key, arr in last:   # the last step's sampled results
-                self._kept(key)[a.rank] = arr
+            seen = set()    # a host buffer holds its slot's last result
+            for key, dst, arr in reversed(last):  # the last step's samples
+                if arr is None and id(dst) in seen:
+                    continue
+                seen.add(id(dst))
+                self._kept(key)[a.rank] = (self._result(dst) if arr is None
+                                           else arr)
 
         run_ranks(self.accls, loop, timeout=BARRIER_S + (seconds or 0))
         win.seconds = st["t1"] - st["t0"]
         return win
+
+    def _result(self, dst):
+        """A result as it stands: a host mirror's copied out of the buffer
+        the next call into its slot overwrites, a device array as is."""
+        return dst.data.copy() if self.host else dst.jax
 
     def _kept(self, key) -> list:
         return self.samples.setdefault(key, [None] * len(self.accls))

@@ -8,7 +8,8 @@ runs it. ``<path>.jsonl`` gets one line per run: the seed, the exit code,
 the wall seconds, the run's result line and the last lines of its stderr.
 The summary line gives, for each metric, the median and the spread: the
 distance between the first and third quartiles (``statistics.quantiles``)
-over the median. A run that fails or is not correct ends the set.
+over the median (None where the median is 0). A run that fails or is not
+correct ends the set.
 """
 
 from __future__ import annotations
@@ -24,9 +25,12 @@ from pathlib import Path
 from . import spec
 
 
-def spread(values) -> float:
+def spread(values) -> float | None:
+    """None where the median is 0 (a count such as lowerings), which has
+    no spread relative to it."""
     q1, _, q3 = statistics.quantiles(values, n=4)
-    return (q3 - q1) / statistics.median(values)
+    median = statistics.median(values)
+    return (q3 - q1) / median if median else None
 
 
 def main(argv=None) -> int:

@@ -12,7 +12,11 @@ metrics. Everything a cell needs is a file found by its name:
   the configuration's ``buffers`` key names;
 * each metric it reports, end-to-end or per-layer: ``metrics/<name>.py``,
   whose ``read(reading)`` returns the number, or None where the run has
-  nothing for it to read.
+  nothing for it to read. A metric named ``<base>.<tag>`` (one quantity,
+  split by the end-to-end metric its cells report) falls back to
+  ``metrics/<base>.py`` where it has no file of its own. The per-layer
+  metrics that move one end-to-end metric carry one tag, so a misspelt tag,
+  which would fall back unseen, is refused.
 
 Adding a cell, a configuration, a call or a metric is adding such files and
 entries; no code here changes.
@@ -51,10 +55,25 @@ def _for_cell(metric: dict, cell: str, reported: set) -> bool:
     return metric.get("moves", metric["name"]) in reported
 
 
+def check_tags(bench: dict) -> None:
+    """Refuse per-layer ``<base>.<tag>`` names whose tags differ among the
+    metrics that move one end-to-end metric."""
+    tags: dict = {}
+    for m in bench["per_layer"]:
+        _, dot, tag = m["name"].partition(".")
+        if dot:
+            tags.setdefault(m["moves"], set()).add(tag)
+    mixed = {k: sorted(v) for k, v in tags.items() if len(v) > 1}
+    if mixed:
+        raise ValueError(f"per-layer metrics that move one end-to-end "
+                         f"metric carry one tag: {mixed}")
+
+
 def find_cell(name: str, bench: dict | None = None, root: Path | None = None,
               home: Path | None = None) -> Cell:
     root, home = root or ROOT, home or HERE
     bench = load_benchmark(root) if bench is None else bench
+    check_tags(bench)
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; it has "
@@ -93,5 +112,9 @@ def module(kind: str, name: str, home: Path | None = None):
 
 
 def reader(metric: str, home: Path | None = None):
-    """The ``read(reading)`` function of ``metrics/<metric>.py``."""
+    """The ``read(reading)`` function of ``metrics/<metric>.py``, or, where
+    that file is missing, of ``metrics/<base>.py`` for a metric named
+    ``<base>.<tag>``."""
+    if not ((home or HERE) / "metrics" / f"{metric}.py").exists():
+        metric = metric.partition(".")[0]
     return module("metrics", metric, home).read

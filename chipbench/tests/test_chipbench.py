@@ -25,16 +25,20 @@ import pytest
 from accl_tpu.device.tpu import TpuDevice, tpu_world
 from accl_tpu.parallel.collectives import MeshCollectives
 
-from chipbench import calibrate, check, data, drive, peaks, run, spec
+from chipbench import calibrate, check, data, drive, peaks, run, sets, spec
 from chipbench import traffic as tr
 from chipbench.trace_reduce import (Chip, Reduction, collective_base,
                                     parse_op, reduce_trace)
 
 HERE = Path(__file__).resolve().parent
 SEED = 2**33 + 17          # seeds wider than 32 bits are welcome
-# tiny sizes of each cell's configuration: three buckets, the last short
+# tiny sizes of each cell's configuration: three buckets, the last short;
+# three sizes; five decode rows
 TINY = {"ddp25.f32": {"parameters": 3000, "bucket_cap_mb": 0.004},
-        "acclbench.local": {"sizes_bytes": [8, 800, 8000]}}
+        "acclbench.local": {"sizes_bytes": [8, 800, 8000]},
+        "acclbench.host": {"sizes_bytes": [8, 800, 8000]},
+        "tp4-decode.bf16": {"batch": 2, "hidden_size": 128,
+                            "num_hidden_layers": 2}}
 
 
 def tiny(name: str) -> spec.Cell:
@@ -176,12 +180,71 @@ def test_draws_are_balanced_so_every_seed_asks_for_the_same_work():
     assert counts[0] == counts[1] == counts[2]
 
 
-def test_ddp_steps_alternate_input_sets_over_every_bucket():
-    cell = spec.find_cell("ddp25.f32")
-    p = tr.plan(cell.traffic, 52, SEED)
-    assert p.steps == 2 and p.op.shape == (2, 52)
-    assert (p.slot == np.arange(52)).all()
+@pytest.mark.parametrize("name,slots", [("ddp25.f32", 52),
+                                        ("tp4-decode.bf16", 65)])
+def test_ddp_steps_alternate_input_sets_over_every_bucket(name, slots):
+    cell = spec.find_cell(name)
+    p = tr.plan(cell.traffic, slots, SEED)
+    assert p.steps == 2 and p.op.shape == (2, slots)
+    assert (p.slot == np.arange(slots)).all()
     assert (p.opnd[0] == 0).all() and (p.opnd[1] == 1).all()
+
+
+def test_tp_rows_are_a_decode_steps_allreduces():
+    """The embedding's allreduce, then each layer's mixer and MLP: 65 rows
+    of batch x hidden, in the order a step issues them."""
+    config = spec.find_cell("tp4-decode.bf16").config
+    assert (config["hidden_size"], config["num_hidden_layers"],
+            config["batch"], config["tensor_parallel"]) == (4096, 32, 32, 4)
+    assert len(config["mixer_types"]) == 32
+    assert tr.sizes(config) == [32 * 4096] * 65
+    assert tr.sizes(dict(config, **TINY["tp4-decode.bf16"])) == [256] * 5
+
+
+def test_buffers_live_where_the_traffic_says():
+    """``"placement": "host"`` gives host-mirror buffers with no device
+    array; the default gives device arrays; any other value is refused."""
+    accls = tpu_world(1)
+    try:
+        for name, host in (("acclbench.local", False),
+                           ("acclbench.host", True)):
+            r = drive.Run(accls, tiny(name), SEED)
+            bufs = r.outs[0] + [b for row in r.ins[0] for b in row]
+            assert len(bufs) == 5 * 3
+            assert all(b.is_device_resident != host for b in bufs)
+            assert all(isinstance(b.data, np.ndarray) for b in bufs)
+            if host:
+                for b in bufs:
+                    with pytest.raises(ValueError, match="device-resident"):
+                        b.jax
+            else:
+                assert all(isinstance(b.jax, jax.Array) for b in bufs)
+            r.results()
+        cell = tiny("acclbench.host")
+        cell = dataclasses.replace(cell, traffic=dict(cell.traffic,
+                                                      placement="pinned"))
+        with pytest.raises(ValueError, match="placement 'pinned'"):
+            drive.Run(accls, cell, SEED)
+    finally:
+        for a in accls:
+            a.deinit()
+
+
+def test_host_results_are_copied_only_where_kept(monkeypatch):
+    """A host mirror's sampled result is copied out in the window only
+    while fewer than ``sample.max`` are kept; the last step's are copied
+    after the window, and every kept result is the call's own."""
+    cell = tiny("acclbench.host")
+    cell = dataclasses.replace(cell, traffic=dict(
+        cell.traffic, sample={"every": 1, "max": 3}))
+    copies = []
+    result = drive.Run._result
+    monkeypatch.setattr(drive.Run, "_result",
+                        lambda self, dst: copies.append(1) or result(self, dst))
+    win, failed, err, _ = one_run(cell)
+    assert failed == 0 and err <= limit(cell)
+    # one call a step, every call with a result sampled: 3 kept, 1 last
+    assert win.steps > 20 and len(copies) <= 4
 
 
 def test_values_match_on_device_and_in_numpy():
@@ -280,8 +343,9 @@ def _no_exchange(monkeypatch):
 
 FAULTS = {"dropped": _drop, "altered": _alter, "half": _half,
           "no_exchange": _no_exchange}
+MULTICHIP = {"ddp25.f32", "tp4-decode.bf16"}     # cells with an exchange
 CASES = [(n, f) for n in sorted(TINY) for f in FAULTS
-         if f != "no_exchange" or n == "ddp25.f32"]
+         if f != "no_exchange" or n in MULTICHIP]
 
 
 @pytest.mark.parametrize("name,fault", CASES)
@@ -313,11 +377,43 @@ def test_end_to_end_readers_on_synthetic_timings():
     r = run.Reading(cell, 19.5, _window(), {}, None, None)
     assert spec.reader("grad_gbs")(r) == 4.0
     assert spec.reader("call_us")(r) == 250_000.0
+    assert spec.reader("step_ms")(r) == 500.0
     assert spec.reader("setup_s")(r) == 19.5
     empty = dataclasses.replace(r, window=_window(steps=0, calls=0,
                                                   seconds=0.0))
     assert spec.reader("grad_gbs")(empty) is None
     assert spec.reader("call_us")(empty) is None
+    assert spec.reader("step_ms")(empty) is None
+
+
+def test_a_tagged_metric_falls_back_to_its_base_reader(tmp_path):
+    """``<base>.<tag>`` reads ``metrics/<base>.<tag>.py`` where it exists,
+    and ``metrics/<base>.py`` otherwise."""
+    for tag in ("bucket", "small", "decode"):
+        assert spec.reader(f"idle_share.{tag}") is spec.reader("idle_share")
+    assert spec.reader("coll_roofline.decode") is spec.reader("coll_roofline")
+    (tmp_path / "metrics").mkdir()
+    (tmp_path / "metrics" / "n.py").write_text(
+        "def read(run):\n    return 1\n")
+    (tmp_path / "metrics" / "n.own.py").write_text(
+        "def read(run):\n    return 2\n")
+    assert spec.reader("n.other", tmp_path)(None) == 1
+    assert spec.reader("n.own", tmp_path)(None) == 2
+    with pytest.raises(KeyError, match="no metrics file 'm'"):
+        spec.reader("m.decode", tmp_path)
+
+
+def test_a_misspelt_tag_is_refused():
+    """A tag that differs from the others moving the same end-to-end
+    metric would fall back to its base reader unseen; the cell is refused."""
+    bench = spec.load_benchmark()
+    spec.check_tags(bench)
+    typo = dict(next(m for m in bench["per_layer"]
+                     if m["name"] == "idle_share.decode"),
+                name="idle_share.decod")
+    bad = dict(bench, per_layer=bench["per_layer"] + [typo])
+    with pytest.raises(ValueError, match="decod"):
+        spec.find_cell("tp4-decode.bf16", bad)
 
 
 def test_per_layer_readers_on_synthetic_timings():
@@ -335,6 +431,14 @@ def test_per_layer_readers_on_synthetic_timings():
     assert spec.reader("idle_share.bucket")(ctx) == pytest.approx(75.0)
     assert spec.reader("coll_roofline")(ctx) == pytest.approx(50.0)
     assert peaks.allreduce_least_s(n, 4, peak)[1] == "ici"
+    # a decode step's bf16 rows: the least time counts two bytes an element
+    rows = 32 * 4096
+    decode = dataclasses.replace(
+        ctx, cell=spec.find_cell("tp4-decode.bf16"),
+        window=_window(itemsize=2,
+                       issued=collections.Counter({("allreduce", rows): 65})),
+        trace=_reduction(0.01, 1.0, 65 * 2 * 3 / 4 * rows * 2 / 200e9 * 4))
+    assert spec.reader("coll_roofline.decode")(decode) == pytest.approx(25.0)
     one = dataclasses.replace(ctx, cell=dataclasses.replace(cell, chips=1))
     assert spec.reader("coll_roofline")(one) is None
     assert spec.reader("driver_call_us.small")(
@@ -346,7 +450,9 @@ def test_per_layer_readers_on_synthetic_timings():
 
 @pytest.mark.parametrize("name,cell", [("plan_hit_share.bucket", "ddp25.f32"),
                                        ("plan_hit_share.small",
-                                        "acclbench.local")])
+                                        "acclbench.local"),
+                                       ("plan_hit_share.decode",
+                                        "tp4-decode.bf16")])
 def test_plan_hit_share_on_synthetic_counts(name, cell):
     share = spec.reader(name)
     cell = spec.find_cell(cell)
@@ -358,6 +464,14 @@ def test_plan_hit_share_on_synthetic_counts(name, cell):
     assert read({"hit": 6, "miss": 1, "fallback": 1}) == 75.0
     assert read({"hit": 0, "miss": 0, "fallback": 0}) is None
     assert read({}) is None and read(None) is None
+
+
+def test_spread_of_a_set():
+    """The quartiles' distance over the median; none for a count whose
+    median is 0, such as the lowerings of a warmed-up window."""
+    assert sets.spread([10.0, 11.0, 9.0, 10.0, 12.0, 8.0]) == pytest.approx(
+        (11.25 - 8.75) / 10.0)
+    assert sets.spread([0, 0, 0]) is None
 
 
 def test_unknown_device_kind_raises():

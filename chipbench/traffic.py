@@ -21,6 +21,8 @@ their type. Keys:
   at least one call of the cycle is); a run keeps the first ``max`` such
   calls of the window and those of its last step.
 * ``limits``: per configuration dtype, the comparison's limits.
+* ``placement`` (optional): ``"device"`` (the default) or ``"host"``,
+  where every operand and result lives (``drive.py``).
 
 A step ends when every rank's results of it are ready. Everything drawn here
 comes from ``--seed`` alone: the same seed gives the same calls, sizes,
