@@ -21,8 +21,14 @@ coordination happens in a host-side rendezvous:
 
 Buffer staging has two modes:
 
-* **Host-mirror buffers** (the default) stage through host numpy per
-  call — API parity with the emulator corpus, ~5x per-call overhead
+* **Host-mirror buffers** (the default) keep their data in host numpy —
+  API parity with the emulator corpus. A dense collective (allreduce,
+  allgather, reduce_scatter, alltoall) whose members all name host
+  mirrors of exact geometry runs from a launch plan of its own: each
+  operand lands on its rank's device straight from host memory, the
+  flat program runs, and each result shard is read back once into its
+  buffer. Everything else (rooted ops, host-side operand or result
+  compression, mixed placements) stages through host numpy per call
   (``benchmarks/driver_overhead.py``).
 * **Device-resident buffers** (``ACCL.buffer(data=<jax.Array>)`` or
   ``device_resident=True`` — the reference's ``to_from_fpga=False``)
@@ -150,31 +156,35 @@ def _flat_geometry(op: CCLOp, w: int, count: int, root: int):
 
 
 class _LaunchPlan:
-    """What the first device-resident launch of a collective signature
-    resolved, kept beside the communicator's programs
+    """What the first launch of a collective signature on one placement
+    of its buffers resolved, kept beside the communicator's programs
     (``MeshCollectives._cache``): the program, the geometry of
-    :func:`_flat_geometry`, the storage dtype, and ``aval``, the flat
+    :func:`_flat_geometry`, the storage dtype, ``host`` (the placement:
+    host mirrors, else device-resident buffers), and ``aval``, the flat
     operand's aval once :meth:`TpuContext.proven_aval` has shown that
     the unchecked constructor builds the same array (None: the public,
     checked one). Later launches of the signature check their members
     against it and go straight to assembly; operands are read from the
     members' buffers on every launch."""
 
-    __slots__ = ("program", "src", "dst", "dtype", "aval")
+    __slots__ = ("program", "src", "dst", "dtype", "host", "aval")
 
-    def __init__(self, program, src, dst, dtype):
+    def __init__(self, program, src, dst, dtype, host=False):
         self.program = program
         self.src = src
         self.dst = dst
         self.dtype = dtype
+        self.host = host
         self.aval = None
 
 
-# tpu_launch_plan_total{result}: hit (a launch ran from its signature's
-# plan), miss (no plan yet: the full resolution ran, and built one when
-# every member was device-resident), fallback (a member did not match the
-# plan: the full resolution ran). Counted on every launch, so kept here
-# and handed to METRICS by a collector when a snapshot is taken.
+# tpu_launch_plan_total{result}: hit (a launch ran from a plan of its
+# signature), miss (no plan of the signature yet, or the launch built the
+# first of its placement: every member device-resident, or every member a
+# host mirror of a dense op), fallback (a plan existed, a member did not
+# match it, and none was built: the full resolution ran). Counted once a
+# launch, so kept here and handed to METRICS by a collector when a
+# snapshot is taken.
 _plan_counts = {"hit": 0, "miss": 0, "fallback": 0}
 _plan_counts_mu = threading.Lock()
 
@@ -720,6 +730,9 @@ class TpuDevice(Device):
         self.ctx = ctx
         self.rank = rank
         self.mem = DeviceMemory()          # host mirrors of device buffers
+        # host mirrors registered in mem: address -> the flat array mem
+        # reads and writes there (its size and dtype are the buffer's)
+        self.host_bufs: dict[int, np.ndarray] = {}
         # device-resident buffers (no host mirror): address -> ACCLBuffer
         # whose .jax is the live array on this rank's device
         self.dev_bufs: dict[int, ACCLBuffer] = {}
@@ -743,13 +756,16 @@ class TpuDevice(Device):
         if buf.is_device_resident:
             self.dev_bufs[buf.address] = buf
         else:
-            self.mem.register(buf.address, buf.data)
+            flat = buf.data.reshape(-1)
+            self.mem.register(buf.address, flat)
+            self.host_bufs[buf.address] = flat
 
     def deregister_buffer(self, buf: ACCLBuffer):
         if buf.is_device_resident:
             self.dev_bufs.pop(buf.address, None)
         else:
             self.mem.deregister(buf.address)
+            self.host_bufs.pop(buf.address, None)
 
     # -- one-sided RMA windows (accl_tpu/rma) ------------------------------
     def register_window(self, wid: int, addr: int, nbytes: int):
@@ -1528,27 +1544,54 @@ class TpuDevice(Device):
         cfg = d0.arithcfg
         devs = [ctx.devices[comm.ranks[r].global_rank] for r in range(W)]
         coll = ctx.coll_for(comm)
-        plan_key = None
+        fresh = host_key = None
         if op in _DENSE_IO or op in _ROOTED:
-            # the launch plan: every decision below that a device-resident
-            # launch of this signature takes, made once. The key is the
-            # descriptor's inputs to those decisions; the plans live in
-            # the device set's collectives, so no other set reaches them.
+            # the launch plan: every decision below that a launch of this
+            # signature takes, made once. The key is the descriptor's
+            # inputs to those decisions; the plans live in the device
+            # set's collectives, so no other set reaches them.
             plan_key = ("plan", op, count, cfg.uncompressed_dtype,
                         cfg.compressed_dtype, getattr(cfg, "quant_block", 0),
                         d0.algorithm, d0.function, d0.compression,
                         d0.root_src_dst if op in _ROOTED else None,
                         ctx.algorithm)
             plan = coll._cache.get(plan_key)
-            if plan is None:
-                _count_plan("miss")
-            else:
+            if plan is not None:
                 got = self._resolve(plan, descs, devs, coll)
                 if got is not None:
                     _count_plan("hit")
                     self._run_flat(coll, got[0], plan, got[1], devs, descs)
                     return 0
-                _count_plan("fallback")
+            # host-mirror members of a dense op: a plan of their own,
+            # under the signature and the placement
+            hplan = None
+            if op in _DENSE_IO:
+                host_key = plan_key + ("host",)
+                hplan = coll._cache.get(host_key)
+                if hplan is not None:
+                    got = self._resolve_host(hplan, descs, devs)
+                    if got is not None:
+                        _count_plan("hit")
+                        self._run_host(coll, got[0], hplan, got[1], descs)
+                        return 0
+            # no kept plan fits: a placement that has none yet gets one
+            # when every member's buffers have its exact geometry (host
+            # mirrors of no elements stage: the flat layout cannot place
+            # an empty shard)
+            geom = _flat_geometry(op, W, count, d0.root_src_dst)
+            dtype = np.dtype(cfg.uncompressed_dtype)
+            got = None
+            if plan is None:
+                fresh = _LaunchPlan(None, *geom, dtype)
+                got = self._resolve(fresh, descs, devs, coll)
+            if (got is None and host_key is not None and hplan is None
+                    and count and not _noncanonical(dtype)):
+                fresh = _LaunchPlan(None, *geom, dtype, host=True)
+                got = self._resolve_host(fresh, descs, devs)
+            if got is None:
+                fresh = None
+            _count_plan("fallback" if fresh is None and (
+                plan is not None or hplan is not None) else "miss")
         wire = (cfg.compressed_dtype
                 if d0.compression & Compression.ETH_COMPRESSED else None)
 
@@ -1618,30 +1661,34 @@ class TpuDevice(Device):
         if op == CCLOp.barrier:
             return 0  # rendezvous above IS the barrier
 
-        # -- device-resident fast path (to_from_fpga=False parity) --------
+        # -- the flat program (to_from_fpga=False parity) ----------------
         # When every buffer a member rank's call names is device-resident
         # with exact geometry (_flat_geometry: for the dense collectives
         # every src and dst; for the rooted ones only the ranks that own
         # data on each side), the collective skips host staging entirely:
         # per-rank arrays assemble into the flat global, one cached
         # program runs, and result shards rebind the destinations — zero
-        # host copies. What this resolved becomes the signature's plan.
-        if plan_key is not None:
-            plan = _LaunchPlan(None, *_flat_geometry(op, W, count, root),
-                               np.dtype(cfg.uncompressed_dtype))
-            got = self._resolve(plan, descs, devs, coll)
-            if got is not None:
-                func = (d0.function if op in (CCLOp.allreduce,
-                                              CCLOp.reduce_scatter,
-                                              CCLOp.reduce)
-                        else ReduceFunc.SUM)
-                plan.program = coll._program_flat(
-                    op.name, alg, func, _wire_name(wire),
-                    root if op in _ROOTED else None, qblock)
-                self._run_flat(coll, got[0], plan, got[1], devs, descs)
-                plan.aval = ctx.proven_aval(coll, got[0])
-                ctx.keep_plan(coll, plan_key, plan)
-                return 0
+        # host copies. Host mirrors of a dense op's exact geometry run the
+        # same program, their rows landed straight from host memory.
+        # What this resolved becomes the signature's plan.
+        if fresh is not None:
+            func = (d0.function if op in (CCLOp.allreduce,
+                                          CCLOp.reduce_scatter,
+                                          CCLOp.reduce)
+                    else ReduceFunc.SUM)
+            fresh.program = coll._program_flat(
+                op.name, alg, func, _wire_name(wire),
+                root if op in _ROOTED else None, qblock)
+            if fresh.host:
+                landed = self._run_host(coll, got[0], fresh, got[1], descs)
+                if landed is not None:
+                    fresh.aval = ctx.proven_aval(coll, landed)
+                ctx.keep_plan(coll, host_key, fresh)
+            else:
+                self._run_flat(coll, got[0], fresh, got[1], devs, descs)
+                fresh.aval = ctx.proven_aval(coll, got[0])
+                ctx.keep_plan(coll, plan_key, fresh)
+            return 0
 
         if op == CCLOp.allreduce:
             x = coll.shard(read_all(lambda d: d.addr_0, count))
@@ -1753,6 +1800,62 @@ class TpuDevice(Device):
                 dst_map[r] = b
         return srcs, dst_map
 
+    def _resolve_host(self, plan: _LaunchPlan, descs, devs):
+        """Every member's operand and destination for a host-mirror launch
+        of ``plan``'s geometry (a dense op): ``(srcs, dsts)``, each the
+        flat host array registered at the descriptor's address. None when
+        a member's call compresses on the host or a buffer it names is not
+        a host mirror of the plan's size and dtype: the caller then takes
+        the staged path."""
+        dtype = plan.dtype
+        srcs, dsts = [], []
+        for r, d in enumerate(descs):
+            if int(d.compression) & _HOST_COMPRESSION:
+                return None
+            bufs = devs[r].host_bufs
+            field, n = plan.src[r]
+            a = bufs.get(getattr(d, field))
+            if a is None or a.size != n or a.dtype != dtype:
+                return None
+            field, n = plan.dst[r]
+            b = bufs.get(getattr(d, field))
+            if b is None or b.size != n or b.dtype != dtype:
+                return None
+            srcs.append(a)
+            dsts.append(b)
+        return srcs, dsts
+
+    def _run_host(self, coll, srcs: list, plan: _LaunchPlan, dsts: list,
+                  descs):
+        """The host-mirror launch: each member's operand goes to its
+        rank's device straight from host memory (a one-rank program takes
+        the host row itself; more ranks land theirs by one batched
+        transfer and assemble the flat operand), ``plan``'s program runs,
+        and each rank's result shard is read back once and written into
+        its host buffer, the write spanned accl.stage.write as the staged
+        path's is (nothing is read on the host: no accl.stage.read).
+        Returns the landed rows (None for one rank). The operands are the
+        members' buffers themselves: every read of them ends before the
+        results are written, so a destination may alias an operand."""
+        spans = SPANS.enabled
+        landed = None
+        if len(srcs) == 1:
+            out = plan.program(srcs[0])
+        else:
+            landed = jax.device_put(srcs, coll.device_list)
+            out = plan.program(self.ctx.assemble_flat(coll, landed,
+                                                      plan.aval))
+        order, datas = self._out_shards(coll, out)
+        for pos, r in enumerate(order):
+            got = np.asarray(datas[pos])
+            if spans:
+                with annotate("accl.stage.write", call=descs[r].span_call,
+                              nbytes=got.nbytes):
+                    dsts[r][...] = got
+            else:
+                dsts[r][...] = got
+        return landed
+
     def _run_flat(self, coll, srcs: list, plan: _LaunchPlan, dst_map: dict,
                   devs, descs) -> None:
         """The device-resident launch: assemble the flat operand from the
@@ -1775,7 +1878,28 @@ class TpuDevice(Device):
         """Rebind a flat program output's per-rank shards onto the
         destination device buffers in ``dst_map`` (rank -> buffer; ranks
         absent from the map — e.g. non-roots of a gather — are dropped
-        without touching any buffer).
+        without touching any buffer)."""
+        order, datas = self._out_shards(coll, out)
+        dtype = out.dtype
+        for pos, r in enumerate(order):
+            db = dst_map.get(r)
+            if db is None:
+                continue
+            # the plan proved each dst's size; a 1-D dst of the result's
+            # dtype has the result's geometry already, so the rebind is
+            # one swap, and only a non-1-D dst needs the general rebind
+            if len(db._shape) != 1:
+                devs[r]._rebind_dev(db, datas[pos])
+            elif db._dtype == dtype:
+                db._swap(datas[pos])
+            else:
+                db._rebind(datas[pos])
+
+    @staticmethod
+    def _out_shards(coll, out):
+        """A flat program output's per-device arrays and, position for
+        position, the comm-local rank each belongs to: ``(order,
+        datas)``.
 
         Shard objects are expensive to build (index/device per shard,
         ~15us each); the position->rank order is a pure function of the
@@ -1801,20 +1925,7 @@ class TpuDevice(Device):
             datas = out._arrays
         else:
             datas = [s.data for s in out.addressable_shards]
-        dtype = out.dtype
-        for pos, r in enumerate(order):
-            db = dst_map.get(r)
-            if db is None:
-                continue
-            # the plan proved each dst's size; a 1-D dst of the result's
-            # dtype has the result's geometry already, so the rebind is
-            # one swap, and only a non-1-D dst needs the general rebind
-            if len(db._shape) != 1:
-                devs[r]._rebind_dev(db, datas[pos])
-            elif db._dtype == dtype:
-                db._swap(datas[pos])
-            else:
-                db._rebind(datas[pos])
+        return order, datas
 
 
 def tpu_world(world_size: int | None = None, platform: str | None = None,

@@ -1,14 +1,17 @@
-"""The TPU tier's launch plans: what the first device-resident launch of a
-collective signature resolves is kept beside the communicator's programs,
-and later launches of the signature check their members against it and
-go straight to assembly, dispatch and rebind.
+"""The TPU tier's launch plans: what the first launch of a collective
+signature on one placement of its buffers resolves is kept beside the
+communicator's programs, and later launches of the signature check their
+members against it and go straight to assembly, dispatch and rebind (or,
+for host mirrors, to landing the rows and reading the results back).
 
 Covers: plan hits bit-identical to the full resolution for every op that
-launches on device; buffers re-registered with another geometry and
-host-mirror members falling back; split and shrunk communicators never
-reaching an older plan; misplaced shards still moved; the verify-once
-guard of the unchecked assembly; the store's cap; and the
-``tpu_launch_plan_total`` counter.
+launches on device; host-plan hits of the dense four bit-identical to the
+staged path, on one rank and on four; buffers re-registered with another
+geometry and host-mirror members of a device plan falling back; host-side
+compression and empty collectives never planned; a device and a host
+plan of one signature side by side; split and shrunk communicators never reaching an older plan;
+misplaced shards still moved; the verify-once guard of the unchecked
+assembly; the store's cap; and the ``tpu_launch_plan_total`` counter.
 """
 
 import numpy as np
@@ -32,6 +35,20 @@ def world():
     yield accls
     for a in accls:
         a.deinit()
+
+
+@pytest.fixture(scope="module")
+def world1():
+    accls = tpu_world(1, platform="cpu")
+    yield accls
+    for a in accls:
+        a.deinit()
+
+
+@pytest.fixture(params=[1, W], ids=["1rank", f"{W}rank"])
+def ranks(request, world, world1):
+    """The one-rank or the four-rank world."""
+    return world1 if request.param == 1 else world
 
 
 def counts() -> dict:
@@ -92,6 +109,39 @@ def launch(world, op: str, n: int, seed: int, reps: int = 1) -> list:
         return (src if op == "bcast" else dst).data.copy()
 
     return run_ranks(world, body)
+
+
+# dense op -> a rank's (operand, result) elements at count n on w ranks
+DENSE = {"allreduce": lambda w, n: (n, n),
+         "allgather": lambda w, n: (n, w * n),
+         "reduce_scatter": lambda w, n: (w * n, n),
+         "alltoall": lambda w, n: (w * n, w * n)}
+
+
+def dense_launch(accls, op: str, n: int, seed: int, reps: int = 1,
+                 host: bool = True) -> list:
+    """Every rank's result of ``reps`` launches of the dense ``op`` on fresh
+    buffers, host mirrors (or device-resident where not ``host``) of the
+    op's exact geometry, holding operands made from ``seed``."""
+    n_in, n_out = DENSE[op](len(accls), n)
+    fn = OPS[op][2]
+
+    def body(a):
+        x = data(n_in, seed, a.rank)
+        src = a.buffer(data=x) if host else dev(a, x)
+        dst = a.buffer((n_out,), np.float32, device_resident=not host)
+        for _ in range(reps):
+            fn(a, src, dst, n)
+        out = dst.data.copy()
+        src.free_buffer()
+        dst.free_buffer()
+        return out
+
+    return run_ranks(accls, body)
+
+
+def same_bits(a: np.ndarray, b: np.ndarray) -> bool:
+    return a.dtype == b.dtype and a.tobytes() == b.tobytes()
 
 
 @pytest.mark.parametrize("op", sorted(OPS))
@@ -166,6 +216,155 @@ def test_reregistered_buffer_falls_back(world, change):
                                    gold, rtol=tol, atol=tol)
     if change == "size":
         assert not fell_back[1][n:].any()
+
+
+@pytest.mark.parametrize("op", sorted(DENSE))
+def test_host_plan_hit_bit_identical_to_staged(ranks, monkeypatch, op):
+    """The first launch of a dense op on host mirrors builds a plan of its
+    own; its hits, on the data it was built on and on other data, leave in
+    each host-mirror destination the bits the staged path leaves there."""
+    coll = ranks[0].device.ctx.coll
+    n = 24
+    drop_plans(coll)
+    c0 = counts()
+    miss = dense_launch(ranks, op, n, seed=16)
+    assert moved(c0) == {"hit": 0, "miss": 1, "fallback": 0}
+    (key,) = plans(coll)
+    assert key[-1] == "host" and plans(coll)[key].host
+    c0 = counts()
+    hit = dense_launch(ranks, op, n, seed=16, reps=2)
+    hit2 = dense_launch(ranks, op, n, seed=17)
+    assert moved(c0) == {"hit": 3, "miss": 0, "fallback": 0}
+    # the reference: the plans dropped, and none built, so every launch
+    # takes the staged path
+    drop_plans(coll)
+    monkeypatch.setattr(tpu.TpuDevice, "_resolve_host",
+                        lambda self, plan, descs, devs: None)
+    c0 = counts()
+    staged = dense_launch(ranks, op, n, seed=16)
+    staged2 = dense_launch(ranks, op, n, seed=17)
+    assert moved(c0) == {"hit": 0, "miss": 2, "fallback": 0}
+    assert not plans(coll)
+    for r in range(len(ranks)):
+        assert same_bits(miss[r], staged[r])
+        assert same_bits(hit[r], staged[r])
+        assert same_bits(hit2[r], staged2[r])
+    assert not all(np.array_equal(a, b) for a, b in zip(hit, hit2))
+
+
+@pytest.mark.parametrize("change", ["size", "dtype"])
+def test_reregistered_host_buffer_falls_back(world, change):
+    """After the host plan is built, rank 1 frees its host-mirror
+    destination and registers another, of another size or dtype but as
+    many bytes or more, at the same address: the plan's check refuses it
+    and the staged path runs, exactly as it does with no plan at all."""
+    coll = world[0].device.ctx.coll
+    n = 40
+
+    def scenario(keep_plan: bool):
+        def body(a):
+            src = a.buffer(data=data(n, 18, a.rank))
+            dst = a.buffer((n,), np.float32)
+            a.allreduce(src, dst, n)          # builds the host plan
+            out = dst
+            if a.rank == 1:
+                dst.free_buffer()
+                shape, dt = (((2 * n,), np.float32) if change == "size"
+                             else ((n,), np.int32))
+                out = ACCLBuffer(shape, dt, device=a.device,
+                                 address=dst.address)
+                if not keep_plan:
+                    drop_plans(coll)
+            a.allreduce(src, dst, n)          # the descriptor is unchanged
+            got = out.data.copy()
+            out.free_buffer()
+            src.free_buffer()
+            return got
+
+        drop_plans(coll)
+        c0 = counts()
+        res = run_ranks(world, body)
+        return res, moved(c0)
+
+    fell_back, c = scenario(keep_plan=True)
+    assert c == {"hit": 0, "miss": 1, "fallback": 1}
+    no_plan, c = scenario(keep_plan=False)
+    assert c == {"hit": 0, "miss": 2, "fallback": 0}
+    assert not plans(coll)    # a staged launch builds no plan
+    gold = sum(data(n, 18, r) for r in range(W))
+    for r in range(W):
+        assert same_bits(fell_back[r], no_plan[r])
+        got = fell_back[r].view(np.float32)[:n]
+        np.testing.assert_allclose(got, gold, rtol=1e-5, atol=1e-5)
+    if change == "size":
+        assert not fell_back[1][n:].any()
+
+
+@pytest.mark.parametrize("which", ["operand", "result"])
+def test_host_compression_never_takes_a_host_plan(ranks, which):
+    """A call that keeps its operand or its result compressed in host
+    memory (f16 beside f32) stages on every launch and builds no plan."""
+    coll = ranks[0].device.ctx.coll
+    n = 48
+    drop_plans(coll)
+    c0 = counts()
+
+    def body(a):
+        x = data(n, 19, a.rank)
+        src = a.buffer(data=x.astype(np.float16) if which == "operand"
+                       else x)
+        dst = a.buffer((n,), np.float16 if which == "result"
+                       else np.float32)
+        for _ in range(2):
+            a.allreduce(src, dst, n)
+        return dst.data.astype(np.float32)
+
+    res = run_ranks(ranks, body)
+    assert moved(c0) == {"hit": 0, "miss": 2, "fallback": 0}
+    assert not plans(coll)
+    gold = sum(data(n, 19, r) for r in range(len(ranks)))
+    for out in res:
+        np.testing.assert_allclose(out, gold, rtol=1e-2, atol=1e-2)
+
+
+def test_empty_host_collective_stages(ranks):
+    """A dense op of no elements on host mirrors builds no plan and
+    completes on the staged path, launch after launch."""
+    coll = ranks[0].device.ctx.coll
+    drop_plans(coll)
+    c0 = counts()
+    out = dense_launch(ranks, "allreduce", 0, seed=22, reps=2)
+    assert moved(c0) == {"hit": 0, "miss": 2, "fallback": 0}
+    assert not plans(coll)
+    assert all(o.size == 0 for o in out)
+
+
+@pytest.mark.parametrize("op", ["allreduce", "alltoall"])
+def test_device_and_host_plans_of_one_signature(ranks, op):
+    """One signature launched on device-resident buffers and on host
+    mirrors keeps one plan of each placement, side by side, and each
+    placement hits its own; both run the same program, to the same
+    bits."""
+    coll = ranks[0].device.ctx.coll
+    n = 52
+    drop_plans(coll)
+    c0 = counts()
+    dev_miss = dense_launch(ranks, op, n, seed=20, host=False)
+    host_miss = dense_launch(ranks, op, n, seed=20)
+    assert moved(c0) == {"hit": 0, "miss": 2, "fallback": 0}
+    kept = plans(coll)
+    (host_key,) = [k for k in kept if kept[k].host]
+    (dev_key,) = [k for k in kept if not kept[k].host]
+    assert host_key == dev_key + ("host",)
+    c0 = counts()
+    dev_hit = dense_launch(ranks, op, n, seed=20, host=False)
+    host_hit = dense_launch(ranks, op, n, seed=20)
+    dev_hit2 = dense_launch(ranks, op, n, seed=20, host=False)
+    assert moved(c0) == {"hit": 3, "miss": 0, "fallback": 0}
+    assert plans(coll) == kept
+    for outs in (host_miss, dev_hit, host_hit, dev_hit2):
+        for r in range(len(ranks)):
+            assert same_bits(outs[r], dev_miss[r])
 
 
 def test_host_mirror_member_falls_back(world):
@@ -338,12 +537,13 @@ def test_counter_counts_hit_miss_and_fallback(world):
     def host(a):
         src = a.buffer(data=data(W * n, 15, a.rank))
         dst = a.buffer((n,), np.float32)
-        a.reduce_scatter(src, dst, n)        # the plan's signature
-        for _ in range(2):                   # one never device-resident
-            a.reduce_scatter(src, dst, n - 1)
+        a.reduce_scatter(src, dst, n)        # the plan's signature on
+        #                                      host mirrors: a plan of its own
+        for _ in range(2):                   # buffers too large for n - 1:
+            a.reduce_scatter(src, dst, n - 1)  # never planned
 
     run_ranks(world, host)
-    assert moved(c0) == {"hit": 2, "miss": 3, "fallback": 1}
+    assert moved(c0) == {"hit": 2, "miss": 4, "fallback": 0}
     text = METRICS.to_prometheus()
     for result in ("hit", "miss", "fallback"):
         assert f'tpu_launch_plan_total{{result="{result}"}}' in text

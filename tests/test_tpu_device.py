@@ -318,6 +318,16 @@ def test_disjoint_comms_execute_concurrently():
             assert release.wait(30), "comm B never released comm A"
             return self._inner.allreduce(x, **kw)
 
+        def _program_flat(self, *args):    # the launch-plan path
+            prog = self._inner._program_flat(*args)
+
+            def parked(x):
+                started.set()
+                assert release.wait(30), "comm B never released comm A"
+                return prog(x)
+
+            return parked
+
     def fn(a):
         if a.rank in (0, 1):
             sub = a.split_communicator([0, 1])
