@@ -1127,8 +1127,8 @@ class ACCL:
                 # the concrete default it will expand is knowable here —
                 # record it so untuned-run history stays usable for
                 # Tuner.ingest_records. Backends with internal AUTO
-                # handling the enum cannot name (TPU 2D trees) get the
-                # honest "AUTO" label instead.
+                # handling (the TPU tier's rooted ops) get the honest
+                # "AUTO" label instead.
                 from .constants import DEFAULT_ALGORITHMS
                 alg_label = (DEFAULT_ALGORITHMS[op].name
                              if (self.tuner is None and

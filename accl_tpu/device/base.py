@@ -73,11 +73,10 @@ class Device(abc.ABC):
     def auto_resolvable_ops(self):
         """Ops whose AUTO the driver may resolve through the tuner before
         issue; None (the default) means every op with an algorithm axis.
-        A backend whose own AUTO handling beats anything the selector
-        enum can express restricts this — the TPU tier's hierarchical
-        2D-mesh tree for rooted scatter/gather/reduce has no enum value,
-        so resolving their AUTO to RING/ROUND_ROBIN would silently
-        degrade it (device/tpu.py overrides)."""
+        A backend that keeps some ops' AUTO for itself restricts this —
+        the TPU tier keeps the rooted ops', whose programs a tuner's
+        choice from move-engine cost models would not improve
+        (device/tpu.py overrides)."""
         return None
 
     # -- shared inline fast-path gate (used by Emu/Sim backends) ----------

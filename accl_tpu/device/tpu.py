@@ -19,27 +19,30 @@ coordination happens in a host-side rendezvous:
   real mesh exactly like the reference's send/recv ride its transport
   (ccl_offload_control.c:339-380).
 
-Buffer staging has two modes:
+Every collective has one launch: one selector (``TpuDevice._program``)
+picks the flat program (``MeshCollectives._program_flat``: global (W*n,)
+arrays whose per-device shards are the ranks' 1-D operands), and only
+the source of the operands and the sink of the results differ:
 
-* **Host-mirror buffers** (the default) keep their data in host numpy —
-  API parity with the emulator corpus. A dense collective (allreduce,
-  allgather, reduce_scatter, alltoall) whose members all name host
-  mirrors of exact geometry runs from a launch plan of its own: each
-  operand lands on its rank's device straight from host memory, the
-  flat program runs, and each result shard is read back once into its
-  buffer. Everything else (rooted ops, host-side operand or result
-  compression, mixed placements) stages through host numpy per call
-  (``benchmarks/driver_overhead.py``).
 * **Device-resident buffers** (``ACCL.buffer(data=<jax.Array>)`` or
-  ``device_resident=True`` — the reference's ``to_from_fpga=False``)
-  skip host staging entirely: collectives assemble the per-rank arrays
-  into the flat global, run one cached program, and rebind each rank's
-  dst to its result shard; send snapshots are zero-copy (jax.Arrays are
-  immutable). The first such launch of a collective signature keeps what
-  it resolved as a launch plan (``_LaunchPlan``), so later launches only
-  check their members against it before assembling. This closes most of
-  the tier gap; ``MeshCollectives`` inside your own pjit/shard_map
-  program remains the absolute-peak path bench.py measures.
+  ``device_resident=True`` — the reference's ``to_from_fpga=False``):
+  the per-rank arrays assemble into the flat global, the program runs,
+  and each rank's dst is rebound to its result shard — no host copy;
+  send snapshots are zero-copy (jax.Arrays are immutable).
+* **Host-mirror buffers** (the default, API parity with the emulator
+  corpus) of a dense collective's exact geometry: each operand lands on
+  its rank's device straight from host memory, the program runs, and
+  each result shard is read back once into its buffer.
+* **Staged** (everything else: rooted ops on host mirrors, host-side
+  operand or result compression, mixed placements, buffers whose size
+  is not the call's): each operand is read into a host row, the rows
+  land as a host mirror's do, and each result is written back through
+  the rank's result path.
+
+The first device-resident or host-mirror launch of a collective
+signature keeps what it resolved as a launch plan (``_LaunchPlan``), so
+later launches only check their members against it. ``MeshCollectives``
+inside your own pjit/shard_map program remains the absolute-peak path.
 """
 
 from __future__ import annotations
@@ -67,7 +70,6 @@ from ..emulator.executor import DeviceMemory
 from ..log import get_logger
 from ..parallel.collectives import MeshCollectives, _wire_name
 from ..parallel.mesh import make_mesh
-from ..parallel.tree import Tree2DCollectives
 from ..rma.window import WindowRegistry
 from ..tracing import METRICS, SPANS, annotate, watch_lowerings
 from .base import Device
@@ -98,13 +100,16 @@ def _payload_bytes(desc: CallDescriptor, count: int | None = None) -> int:
     return n * cfg.uncompressed_elem_bytes if cfg is not None else 0
 
 
-def _factor_2d(w: int) -> tuple[int, int]:
-    """Largest divisor pair (outer, inner) with outer <= inner — the 2D
-    mesh shape the tree collectives ride. (1, w) means no 2D structure."""
-    o = int(w ** 0.5)
-    while o > 1 and w % o:
-        o -= 1
-    return o, w // o
+def _legal(desc: CallDescriptor) -> bool:
+    """Whether the call's algorithm selector is legal for its op: the
+    table every tier validates with, so an illegal pair fails identically
+    everywhere."""
+    try:
+        check_algorithm(desc.scenario.name, desc.algorithm)
+    except ValueError:
+        return False
+    return True
+
 
 _COLLECTIVES = {CCLOp.bcast, CCLOp.scatter, CCLOp.gather, CCLOp.reduce,
                 CCLOp.allgather, CCLOp.allreduce, CCLOp.reduce_scatter,
@@ -130,13 +135,12 @@ _HOST_COMPRESSION = int(Compression.OP0_COMPRESSED
 
 
 def _flat_geometry(op: CCLOp, w: int, count: int, root: int):
-    """Where a device-resident launch of ``op`` finds each rank's operand
-    and puts its result: ``(src, dst)``, per rank a descriptor address
-    field and its elements. A src field of None is a cached device zero
-    shard (a scatter's non-roots: the binomial schedule's first hop from
-    root never reads them); a dst of None is no result. A bcast runs in
-    place on addr_0: root's is the source, every other rank's the
-    destination."""
+    """Where a launch of ``op`` finds each rank's operand and puts its
+    result: ``(src, dst)``, per rank a descriptor address field and its
+    elements. A src field of None is a zero row (a scatter's non-roots:
+    the binomial schedule's first hop from root never reads them); a dst
+    of None is no result. A bcast runs in place on addr_0: root's is the
+    source, every other rank's the destination."""
     if op in _DENSE_IO:
         n_in, n_out = _DENSE_IO[op](w, count)
         return [("addr_0", n_in)] * w, [("addr_2", n_out)] * w
@@ -254,9 +258,6 @@ class TpuContext:
         self.world_size = mesh.shape[axis_name]
         self.coll = MeshCollectives(mesh, axis_name)
         self._subcolls: dict[int, MeshCollectives] = {}
-        self._subtrees: dict[int, Tree2DCollectives | None] = {}
-        self.tree = self._make_tree(
-            list(np.asarray(mesh.devices).reshape(-1)))
         self.algorithm = algorithm
         self.devices: list[TpuDevice | None] = [None] * self.world_size
         # rendezvous state
@@ -541,19 +542,6 @@ class TpuContext:
             time.sleep(0.2 if next_dl is None
                        else min(max(next_dl - now, 0.001), 0.2))
 
-    @staticmethod
-    def _make_tree(devs) -> Tree2DCollectives | None:
-        """Hierarchical collectives over the same devices folded into the
-        largest 2D factorization — the bandwidth-correct path for rooted
-        ops at scale (BASELINE config 4's 32-rank (8,4) trees). None when
-        the world has no 2D structure (prime or < 4 ranks)."""
-        from jax.sharding import Mesh
-        o, i = _factor_2d(len(devs))
-        if o < 2:
-            return None
-        return Tree2DCollectives(
-            Mesh(np.asarray(devs).reshape(o, i), ("outer", "inner")))
-
     def _comm_devices(self, comm: Communicator) -> list:
         """The communicator's devices in comm-local rank order (one
         rank->device convention for every sub-mesh built from the world)."""
@@ -578,19 +566,6 @@ class TpuContext:
             self.axis_name)
         with self._lock:
             return self._subcolls.setdefault(key, sub)
-
-    def tree_for(self, comm: Communicator) -> Tree2DCollectives | None:
-        """The communicator's 2D tree context (None when its size has no
-        2D factorization)."""
-        if comm.size == self.world_size:
-            return self.tree
-        key = comm.comm_id
-        with self._lock:
-            if key in self._subtrees:
-                return self._subtrees[key]
-        tree = self._make_tree(self._comm_devices(comm))
-        with self._lock:
-            return self._subtrees.setdefault(key, tree)
 
 
 class DeviceStreamPort:
@@ -828,15 +803,11 @@ class TpuDevice(Device):
 
     def auto_resolvable_ops(self):
         """The rooted ops (bcast/scatter/gather/reduce) keep their AUTO:
-        on 2D meshes it lowers to the hierarchical tree (O(outer+inner)
-        fan-out), and a tuner resolving AUTO to ROUND_ROBIN/RING would
-        force the masked 1-D lowering — allreduce/allgather-class
-        traffic regardless of root — based on cost models shaped for the
-        move-engine tiers. (bcast does have a TREE selector, but the
-        tuner's small-message choice would be ROUND_ROBIN, the exact
-        degradation; callers who want the 1-D path can force it.) The
-        dense collectives map cleanly onto the xla/ring axis the tuner
-        chooses between."""
+        bcast, scatter and gather run their binomial schedules whatever
+        the selector, and a tuner resolving a reduce's AUTO to RING would
+        force the ring lowering on cost models shaped for the move-engine
+        tiers. The dense collectives map cleanly onto the xla/ring axis
+        the tuner chooses between."""
         return frozenset({"allreduce", "allgather", "reduce_scatter"})
 
     # Inline eligibility in the submitting thread, preserving the async
@@ -1531,7 +1502,11 @@ class TpuDevice(Device):
                 h.complete(err, exception=exc_out)
 
     def _launch(self, descs: list, comm: Communicator) -> int:
-        """Execute one collective for all member ranks (no locks held)."""
+        """Execute one collective for all member ranks (no locks held).
+        Every launch runs the program :meth:`_program` selects: from a
+        kept plan whose check its members pass, from the plan it builds
+        when every member's buffers have the op's exact geometry on one
+        placement, else staged (:meth:`_stage`)."""
         ctx = self.ctx
         d0 = descs[0]
         op = d0.scenario
@@ -1541,86 +1516,98 @@ class TpuDevice(Device):
         W = comm.size
         if op in _ROOTED and not 0 <= d0.root_src_dst < W:
             return int(ErrorCode.INVALID_CALL)
+        if op == CCLOp.barrier:   # the rendezvous above IS the barrier
+            return 0 if _legal(d0) else int(ErrorCode.INVALID_CALL)
         cfg = d0.arithcfg
         devs = [ctx.devices[comm.ranks[r].global_rank] for r in range(W)]
         coll = ctx.coll_for(comm)
-        fresh = host_key = None
-        if op in _DENSE_IO or op in _ROOTED:
-            # the launch plan: every decision below that a launch of this
-            # signature takes, made once. The key is the descriptor's
-            # inputs to those decisions; the plans live in the device
-            # set's collectives, so no other set reaches them.
-            plan_key = ("plan", op, count, cfg.uncompressed_dtype,
-                        cfg.compressed_dtype, getattr(cfg, "quant_block", 0),
-                        d0.algorithm, d0.function, d0.compression,
-                        d0.root_src_dst if op in _ROOTED else None,
-                        ctx.algorithm)
-            plan = coll._cache.get(plan_key)
-            if plan is not None:
-                got = self._resolve(plan, descs, devs, coll)
+        # the launch plan: every decision below that a launch of this
+        # signature takes, made once. The key is the descriptor's inputs
+        # to those decisions; the plans live in the device set's
+        # collectives, so no other set reaches them.
+        plan_key = ("plan", op, count, cfg.uncompressed_dtype,
+                    cfg.compressed_dtype, getattr(cfg, "quant_block", 0),
+                    d0.algorithm, d0.function, d0.compression,
+                    d0.root_src_dst if op in _ROOTED else None,
+                    ctx.algorithm)
+        plan = coll._cache.get(plan_key)
+        if plan is not None:
+            got = self._resolve(plan, descs, devs, coll)
+            if got is not None:
+                _count_plan("hit")
+                self._run_flat(coll, got[0], plan, got[1], devs, descs)
+                return 0
+        # host-mirror members of a dense op: a plan of their own, under
+        # the signature and the placement
+        hplan = None
+        if op in _DENSE_IO:
+            hplan = coll._cache.get(plan_key + ("host",))
+            if hplan is not None:
+                got = self._resolve_host(hplan, descs, devs)
                 if got is not None:
                     _count_plan("hit")
-                    self._run_flat(coll, got[0], plan, got[1], devs, descs)
+                    self._run_host(coll, got[0], hplan, got[1], descs)
                     return 0
-            # host-mirror members of a dense op: a plan of their own,
-            # under the signature and the placement
-            hplan = None
-            if op in _DENSE_IO:
-                host_key = plan_key + ("host",)
-                hplan = coll._cache.get(host_key)
-                if hplan is not None:
-                    got = self._resolve_host(hplan, descs, devs)
-                    if got is not None:
-                        _count_plan("hit")
-                        self._run_host(coll, got[0], hplan, got[1], descs)
-                        return 0
-            # no kept plan fits: a placement that has none yet gets one
-            # when every member's buffers have its exact geometry (host
-            # mirrors of no elements stage: the flat layout cannot place
-            # an empty shard)
-            geom = _flat_geometry(op, W, count, d0.root_src_dst)
-            dtype = np.dtype(cfg.uncompressed_dtype)
-            got = None
+        # no kept plan fits: a placement that has none yet gets one when
+        # every member's buffers have its exact geometry (a collective of
+        # no elements runs no program: the flat layout cannot place an
+        # empty shard)
+        geom = _flat_geometry(op, W, count, d0.root_src_dst)
+        dtype = np.dtype(cfg.uncompressed_dtype)
+        fresh = got = None
+        if count:
             if plan is None:
                 fresh = _LaunchPlan(None, *geom, dtype)
                 got = self._resolve(fresh, descs, devs, coll)
-            if (got is None and host_key is not None and hplan is None
-                    and count and not _noncanonical(dtype)):
+            if got is None and op in _DENSE_IO and hplan is None:
                 fresh = _LaunchPlan(None, *geom, dtype, host=True)
                 got = self._resolve_host(fresh, descs, devs)
-            if got is None:
-                fresh = None
-            _count_plan("fallback" if fresh is None and (
-                plan is not None or hplan is not None) else "miss")
-        wire = (cfg.compressed_dtype
-                if d0.compression & Compression.ETH_COMPRESSED else None)
-
-        def read_all(addr_of, n):
-            rows = []
-            for r, d in enumerate(descs):
-                addr = addr_of(d)
-                if addr:
-                    rows.append(devs[r]._read_operand(
-                        addr, n, d, Compression.OP0_COMPRESSED))
-                else:
-                    rows.append(np.zeros(n, cfg.uncompressed_dtype))
-            return rows
-
-        alg = ctx.algorithm
-        # per-call selector (CollectiveAlgorithm) overrides the context
-        # default: ring variants lower to the shard_map ppermute rings,
-        # everything else to XLA's native collectives. Validation uses the
-        # same table as the emulator tiers so invalid (op, algorithm) pairs
-        # fail identically everywhere.
-        try:
-            check_algorithm(op.name, d0.algorithm)
-        except ValueError:
+        _count_plan("fallback" if got is None and (
+            plan is not None or hplan is not None) else "miss")
+        if _noncanonical(dtype):
+            # jax holds a 64-bit payload in 32 bits with x64 off: refuse
+            # it before any device_put, as _write_staged refuses landing
+            # one, rather than return a truncated result
             return int(ErrorCode.INVALID_CALL)
+        program = self._program(coll, d0)
+        if program is None:
+            return int(ErrorCode.INVALID_CALL)
+        if not count:
+            return 0
+        if got is None:
+            self._stage(coll, program, geom, descs, devs)
+            return 0
+        fresh.program = program
+        if fresh.host:
+            landed = self._run_host(coll, got[0], fresh, got[1], descs)
+            if landed is not None:
+                fresh.aval = ctx.proven_aval(coll, landed)
+            ctx.keep_plan(coll, plan_key + ("host",), fresh)
+        else:
+            self._run_flat(coll, got[0], fresh, got[1], devs, descs)
+            fresh.aval = ctx.proven_aval(coll, got[0])
+            ctx.keep_plan(coll, plan_key, fresh)
+        return 0
+
+    def _program(self, coll: MeshCollectives, d0: CallDescriptor):
+        """The flat program of a collective call, the one every placement
+        of its buffers runs (the program cache's key, no other); None
+        when the call's algorithm selector is not legal for its op."""
+        if not _legal(d0):
+            return None
+        op = d0.scenario
+        cfg = d0.arithcfg
+        # the per-call selector overrides the context default: ring
+        # variants lower to the shard_map ppermute rings, everything else
+        # to XLA's native collectives
+        alg = self.ctx.algorithm
         if d0.algorithm in (CollectiveAlgorithm.RING,
                             CollectiveAlgorithm.FUSED_RING):
             alg = "ring"
         elif d0.algorithm != CollectiveAlgorithm.AUTO:
             alg = "xla"
+        wire = (cfg.compressed_dtype
+                if d0.compression & Compression.ETH_COMPRESSED else None)
         # block-scaled quantized wire (compress_dtype=..., block_scale=True
         # at the driver): the dense ring collectives take the fused Pallas
         # quantize->combine->requant lane — qblock selects it and pins the
@@ -1640,135 +1627,33 @@ class TpuDevice(Device):
                 alg = "ring"
             else:
                 wire = None
-        # rooted ops default to the hierarchical 2D-mesh tree when the comm
-        # has 2D structure — O(outer+inner) hop fan-out instead of the
-        # psum/all_gather-class traffic of the masked 1-D lowerings (which
-        # cost allreduce/allgather bandwidth regardless of root). Explicit
-        # ROUND_ROBIN/RING selectors keep the 1-D path; the explicit TREE
-        # selector (legal for bcast/gather/reduce, VALID_ALGORITHMS) pins
-        # the tree — scatter reaches it via AUTO only. Rooted reduce
-        # rides the tree only uncompressed: the tree has no
-        # wire-compression lanes, and the compressed 1-D path's
-        # decompress-before-arith numerics must win.
-        use_tree = (op in _ROOTED
-                    and (d0.algorithm == CollectiveAlgorithm.AUTO
-                         or (d0.algorithm == CollectiveAlgorithm.TREE
-                             and op in (CCLOp.bcast, CCLOp.gather,
-                                        CCLOp.reduce)))
-                    and not (op == CCLOp.reduce and wire is not None))
-        tree = ctx.tree_for(comm) if use_tree else None
-        root = d0.root_src_dst
-        if op == CCLOp.barrier:
-            return 0  # rendezvous above IS the barrier
+        func = (d0.function if op in (CCLOp.allreduce, CCLOp.reduce_scatter,
+                                      CCLOp.reduce)
+                else ReduceFunc.SUM)
+        return coll._program_flat(
+            op.name, alg, func, _wire_name(wire),
+            d0.root_src_dst if op in _ROOTED else None, qblock)
 
-        # -- the flat program (to_from_fpga=False parity) ----------------
-        # When every buffer a member rank's call names is device-resident
-        # with exact geometry (_flat_geometry: for the dense collectives
-        # every src and dst; for the rooted ones only the ranks that own
-        # data on each side), the collective skips host staging entirely:
-        # per-rank arrays assemble into the flat global, one cached
-        # program runs, and result shards rebind the destinations — zero
-        # host copies. Host mirrors of a dense op's exact geometry run the
-        # same program, their rows landed straight from host memory.
-        # What this resolved becomes the signature's plan.
-        if fresh is not None:
-            func = (d0.function if op in (CCLOp.allreduce,
-                                          CCLOp.reduce_scatter,
-                                          CCLOp.reduce)
-                    else ReduceFunc.SUM)
-            fresh.program = coll._program_flat(
-                op.name, alg, func, _wire_name(wire),
-                root if op in _ROOTED else None, qblock)
-            if fresh.host:
-                landed = self._run_host(coll, got[0], fresh, got[1], descs)
-                if landed is not None:
-                    fresh.aval = ctx.proven_aval(coll, landed)
-                ctx.keep_plan(coll, host_key, fresh)
-            else:
-                self._run_flat(coll, got[0], fresh, got[1], devs, descs)
-                fresh.aval = ctx.proven_aval(coll, got[0])
-                ctx.keep_plan(coll, plan_key, fresh)
-            return 0
-
-        if op == CCLOp.allreduce:
-            x = coll.shard(read_all(lambda d: d.addr_0, count))
-            out = np.asarray(coll.allreduce(x, func=d0.function,
-                                            algorithm=alg, wire_dtype=wire,
-                                            qblock=qblock))
-            for r, d in enumerate(descs):
-                devs[r]._write_result(d.addr_2, out[r], d)
-            return 0
-        if op == CCLOp.reduce:
-            rows = read_all(lambda d: d.addr_0, count)
-            if tree is not None:
-                out = np.asarray(tree.reduce(tree.shard(rows), root=root,
-                                             func=d0.function))
-            else:
-                out = np.asarray(coll.reduce(coll.shard(rows), root=root,
-                                             func=d0.function,
-                                             wire_dtype=wire))
-            devs[root]._write_result(descs[root].addr_2, out[root],
-                                     descs[root])
-            return 0
-        if op == CCLOp.reduce_scatter:
-            x = coll.shard(read_all(lambda d: d.addr_0, W * count))
-            out = np.asarray(coll.reduce_scatter(x, func=d0.function,
-                                                 algorithm=alg,
-                                                 wire_dtype=wire,
-                                                 qblock=qblock))
-            for r, d in enumerate(descs):
-                devs[r]._write_result(d.addr_2, out[r], d)
-            return 0
-        if op == CCLOp.allgather:
-            x = coll.shard(read_all(lambda d: d.addr_0, count))
-            out = np.asarray(coll.allgather(x, algorithm=alg,
-                                            wire_dtype=wire, qblock=qblock))
-            for r, d in enumerate(descs):
-                devs[r]._write_result(d.addr_2, out[r], d)
-            return 0
-        if op == CCLOp.bcast:
-            rows = read_all(lambda d: d.addr_0, count)
-            if tree is not None:
-                out = np.asarray(tree.bcast(tree.shard(rows), root=root,
-                                            wire_dtype=wire))
-            else:
-                out = np.asarray(coll.bcast(coll.shard(rows), root=root,
-                                            wire_dtype=wire))
-            for r, d in enumerate(descs):
-                if r != root:  # root's own buffer never crossed the wire
-                    devs[r]._write_result(d.addr_0, out[r], d)
-            return 0
-        if op == CCLOp.scatter:
-            rows = read_all(lambda d: d.addr_0, W * count)
-            if tree is not None:
-                out = np.asarray(tree.scatter(tree.shard(rows), root=root,
-                                              wire_dtype=wire))
-            else:
-                out = np.asarray(coll.scatter(coll.shard(rows), root=root,
-                                              wire_dtype=wire))
-            for r, d in enumerate(descs):
-                devs[r]._write_result(d.addr_2, out[r], d)
-            return 0
-        if op == CCLOp.gather:
-            rows = read_all(lambda d: d.addr_0, count)
-            if tree is not None:
-                out = np.asarray(tree.gather(tree.shard(rows), root=root,
-                                             wire_dtype=wire))
-            else:
-                out = np.asarray(coll.gather(coll.shard(rows), root=root,
-                                             wire_dtype=wire))
-            devs[root]._write_result(descs[root].addr_2, out[root],
-                                     descs[root])
-            return 0
-        if op == CCLOp.alltoall:
-            x = coll.shard(read_all(lambda d: d.addr_0, W * count))
-            # the program casts chunks on the wire and restores each
-            # rank's self chunk exact (emulator-tier wire_q_except parity)
-            out = np.asarray(coll.alltoall(x, wire_dtype=wire))
-            for r, d in enumerate(descs):
-                devs[r]._write_result(d.addr_2, out[r], d)
-            return 0
-        return int(ErrorCode.COLLECTIVE_NOT_IMPLEMENTED)
+    def _stage(self, coll, program, geom, descs, devs) -> None:
+        """The staged launch, for members no plan takes: each rank's
+        operand of :func:`_flat_geometry` ``geom`` is read into a host row
+        (a zero row where the rank has none), the rows run ``program`` as
+        a host plan's do, and each result a rank has a destination for
+        goes out through its result path."""
+        src, dst = geom
+        rows = []
+        for r, d in enumerate(descs):
+            field, n = src[r]
+            rows.append(np.zeros(n, d.arithcfg.uncompressed_dtype)
+                        if field is None else devs[r]._read_operand(
+                            getattr(d, field), n, d,
+                            Compression.OP0_COMPRESSED))
+        _, order, datas = self._run_rows(coll, program, rows)
+        for pos, r in enumerate(order):
+            if dst[r] is not None:
+                d = descs[r]
+                devs[r]._write_result(getattr(d, dst[r][0]),
+                                      np.asarray(datas[pos]), d)
 
     def _resolve(self, plan: _LaunchPlan, descs, devs, coll):
         """Every member's operand array and destination buffer for a
@@ -1827,25 +1712,17 @@ class TpuDevice(Device):
 
     def _run_host(self, coll, srcs: list, plan: _LaunchPlan, dsts: list,
                   descs):
-        """The host-mirror launch: each member's operand goes to its
-        rank's device straight from host memory (a one-rank program takes
-        the host row itself; more ranks land theirs by one batched
-        transfer and assemble the flat operand), ``plan``'s program runs,
-        and each rank's result shard is read back once and written into
-        its host buffer, the write spanned accl.stage.write as the staged
-        path's is (nothing is read on the host: no accl.stage.read).
-        Returns the landed rows (None for one rank). The operands are the
-        members' buffers themselves: every read of them ends before the
-        results are written, so a destination may alias an operand."""
+        """The host-mirror launch: ``plan``'s program runs on the members'
+        host operands (:meth:`_run_rows`), and each rank's result shard is
+        read back once and written into its host buffer, the write
+        spanned accl.stage.write as the staged path's is (nothing is read
+        on the host: no accl.stage.read). Returns the landed rows (None
+        for one rank). The operands are the members' buffers themselves:
+        every read of them ends before the results are written, so a
+        destination may alias an operand."""
         spans = SPANS.enabled
-        landed = None
-        if len(srcs) == 1:
-            out = plan.program(srcs[0])
-        else:
-            landed = jax.device_put(srcs, coll.device_list)
-            out = plan.program(self.ctx.assemble_flat(coll, landed,
-                                                      plan.aval))
-        order, datas = self._out_shards(coll, out)
+        landed, order, datas = self._run_rows(coll, plan.program, srcs,
+                                              plan.aval)
         for pos, r in enumerate(order):
             got = np.asarray(datas[pos])
             if spans:
@@ -1855,6 +1732,21 @@ class TpuDevice(Device):
             else:
                 dsts[r][...] = got
         return landed
+
+    def _run_rows(self, coll, program, rows: list, aval=None):
+        """Run ``program`` on host rows, one per comm-local rank: a
+        one-rank program takes its row itself; more ranks land theirs by
+        one batched transfer and assemble the flat operand (unchecked only
+        under a plan's proven ``aval``). Returns the landed rows (None for
+        one rank) and the result's per-rank arrays with, position for
+        position, the rank each belongs to: ``(landed, order, datas)``."""
+        landed = None
+        if len(rows) == 1:
+            out = program(rows[0])
+        else:
+            landed = jax.device_put(rows, coll.device_list)
+            out = program(self.ctx.assemble_flat(coll, landed, aval))
+        return (landed, *self._out_shards(coll, out))
 
     def _run_flat(self, coll, srcs: list, plan: _LaunchPlan, dst_map: dict,
                   devs, descs) -> None:

@@ -433,7 +433,9 @@ class MeshCollectives:
 
     Global layout convention (SPMD controller view): operands carry a
     leading ``W`` axis — element [r] is rank r's operand — sharded over the
-    mesh axis. This is the TPU-backend currency the ACCL driver uses.
+    mesh axis. This stacked layout is the public API's; the ACCL driver's
+    TPU tier runs only the flat layout of :meth:`_program_flat`, built
+    from the same per-shard bodies.
 
     Programs are jitted and cached per (op, algorithm, shapes, dtypes).
     """

@@ -286,13 +286,21 @@ def test_run_async_submission_does_not_block_on_launch():
     real = ctx.coll
     release = threading.Event()
 
+    parked = threading.Event()
+
     class Slow:
         def __getattr__(self, name):
             return getattr(real, name)
 
-        def allreduce(self, x, **kw):
-            assert release.wait(10), "launch never released"
-            return real.allreduce(x, **kw)
+        def _program_flat(self, *args):    # the one launch's program
+            prog = real._program_flat(*args)
+
+            def slow(x):
+                parked.set()
+                assert release.wait(10), "launch never released"
+                return prog(x)
+
+            return slow
 
     ctx.coll = Slow()
     try:
@@ -307,6 +315,8 @@ def test_run_async_submission_does_not_block_on_launch():
         submit_elapsed = time.monotonic() - t0
         # submissions returned while the launch is still parked
         assert submit_elapsed < 5.0
+        assert not handles[1].done()
+        assert parked.wait(10), "the launch never reached its program"
         assert not handles[1].done()
         release.set()
         for h in handles:

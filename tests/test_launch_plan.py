@@ -6,7 +6,8 @@ for host mirrors, to landing the rows and reading the results back).
 
 Covers: plan hits bit-identical to the full resolution for every op that
 launches on device; host-plan hits of the dense four bit-identical to the
-staged path, on one rank and on four; buffers re-registered with another
+staged path, on one rank and on four; both checked against the stacked
+``MeshCollectives`` program on the same rows; buffers re-registered with another
 geometry and host-mirror members of a device plan falling back; host-side
 compression and empty collectives never planned; a device and a host
 plan of one signature side by side; split and shrunk communicators never reaching an older plan;
@@ -144,8 +145,42 @@ def same_bits(a: np.ndarray, b: np.ndarray) -> bool:
     return a.dtype == b.dtype and a.tobytes() == b.tobytes()
 
 
+# which comm-local ranks a rooted op at ROOT writes a result to
+RECEIVES = {"bcast": lambda r: r != ROOT, "reduce": lambda r: r == ROOT,
+            "gather": lambda r: r == ROOT}
+
+
+def stacked(coll, op: str, n: int, seed: int) -> list:
+    """Every rank's result of ``op`` on the operands ``launch`` and
+    ``dense_launch`` make from ``seed``, computed outside the driver by the
+    stacked ``MeshCollectives`` program on the same rows: a second
+    lowering of the op. A rank an op writes no result to keeps what its
+    buffer held: a bcast root its operand, any other rank zeros."""
+    k_in, k_out, _ = OPS[op]
+    if op in DENSE:
+        k_in, k_out = (k // n for k in DENSE[op](coll.W, n))
+    rows = [data(k_in * n, seed, r) for r in range(coll.W)]
+    x = coll.shard(rows)
+    out = np.asarray({
+        "allreduce": lambda: coll.allreduce(x),
+        "allgather": lambda: coll.allgather(x),
+        "reduce_scatter": lambda: coll.reduce_scatter(x),
+        "alltoall": lambda: coll.alltoall(x),
+        "bcast": lambda: coll.bcast(x, root=ROOT),
+        "reduce": lambda: coll.reduce(x, root=ROOT, func=ReduceFunc.MAX),
+        "scatter": lambda: coll.scatter(x, root=ROOT),
+        "gather": lambda: coll.gather(x, root=ROOT),
+    }[op]())
+    receives = RECEIVES.get(op, lambda r: True)
+    return [out[r] if receives(r)
+            else rows[r] if op == "bcast"
+            else np.zeros(k_out * n, np.float32) for r in range(coll.W)]
+
+
 @pytest.mark.parametrize("op", sorted(OPS))
 def test_plan_hit_bit_identical_to_full_resolution(world, op):
+    """A plan's hits leave the bits of the launch that built it, and that
+    launch the bits of the stacked program on the same rows."""
     coll = world[0].device.ctx.coll
     n = 24
     drop_plans(coll)
@@ -153,6 +188,8 @@ def test_plan_hit_bit_identical_to_full_resolution(world, op):
     miss = launch(world, op, n, seed=1)
     assert moved(c0) == {"hit": 0, "miss": 1, "fallback": 0}
     assert len(plans(coll)) == 1
+    for m, ref in zip(miss, stacked(coll, op, n, seed=1)):
+        assert same_bits(m, ref)
     c0 = counts()
     hit = launch(world, op, n, seed=1, reps=2)
     assert moved(c0) == {"hit": 2, "miss": 0, "fallback": 0}
@@ -222,7 +259,8 @@ def test_reregistered_buffer_falls_back(world, change):
 def test_host_plan_hit_bit_identical_to_staged(ranks, monkeypatch, op):
     """The first launch of a dense op on host mirrors builds a plan of its
     own; its hits, on the data it was built on and on other data, leave in
-    each host-mirror destination the bits the staged path leaves there."""
+    each host-mirror destination the bits the staged path leaves there,
+    and the bits of the stacked program on the same rows."""
     coll = ranks[0].device.ctx.coll
     n = 24
     drop_plans(coll)
@@ -245,7 +283,9 @@ def test_host_plan_hit_bit_identical_to_staged(ranks, monkeypatch, op):
     staged2 = dense_launch(ranks, op, n, seed=17)
     assert moved(c0) == {"hit": 0, "miss": 2, "fallback": 0}
     assert not plans(coll)
+    ref = stacked(coll, op, n, seed=16)
     for r in range(len(ranks)):
+        assert same_bits(miss[r], ref[r])
         assert same_bits(miss[r], staged[r])
         assert same_bits(hit[r], staged[r])
         assert same_bits(hit2[r], staged2[r])
@@ -329,7 +369,7 @@ def test_host_compression_never_takes_a_host_plan(ranks, which):
 
 def test_empty_host_collective_stages(ranks):
     """A dense op of no elements on host mirrors builds no plan and
-    completes on the staged path, launch after launch."""
+    completes, launch after launch, without running a program."""
     coll = ranks[0].device.ctx.coll
     drop_plans(coll)
     c0 = counts()

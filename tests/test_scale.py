@@ -236,7 +236,7 @@ _TREE32 = textwrap.dedent("""
     np.testing.assert_array_equal(out[root], np.concatenate(ins))
 
     # the DRIVER tier at the same rank count: 32 ACCL ranks rendezvousing
-    # over the 32-vdev mesh (allreduce + tree-routed rooted bcast)
+    # over the 32-vdev mesh (allreduce + rooted bcast)
     from accl_tpu.device.tpu import tpu_world
     from accl_tpu.testing import run_ranks
     accls = tpu_world(32)

@@ -310,15 +310,7 @@ def test_disjoint_comms_execute_concurrently():
         def __getattr__(self, name):   # the launch's plan store, etc.
             return getattr(self._inner, name)
 
-        def shard(self, rows):
-            return self._inner.shard(rows)
-
-        def allreduce(self, x, **kw):
-            started.set()
-            assert release.wait(30), "comm B never released comm A"
-            return self._inner.allreduce(x, **kw)
-
-        def _program_flat(self, *args):    # the launch-plan path
+        def _program_flat(self, *args):    # the one launch's program
             prog = self._inner._program_flat(*args)
 
             def parked(x):
@@ -370,13 +362,21 @@ def test_waiter_survives_slow_execution():
     ctx = accls[0].device.ctx
     real = ctx.coll
 
+    ran = []
+
     class Slow:
         def __getattr__(self, name):
             return getattr(real, name)
 
-        def allreduce(self, x, **kw):
-            time.sleep(1.5)          # longer than the rendezvous timeout
-            return real.allreduce(x, **kw)
+        def _program_flat(self, *args):    # the one launch's program
+            prog = real._program_flat(*args)
+
+            def slow(x):
+                ran.append(1)
+                time.sleep(1.5)      # longer than the rendezvous timeout
+                return prog(x)
+
+            return slow
 
     ctx.coll = Slow()
     try:
@@ -388,21 +388,34 @@ def test_waiter_survives_slow_execution():
             return dst.data[0]
 
         res = run_ranks(accls, fn)
+        assert ran                # the launch did run the slow program
         assert res == [3.0, 3.0]
         assert not ctx._pending  # no leaked rendezvous state
     finally:
         ctx.coll = real
 
 
+def _pop_flat(coll, ops, root) -> set:
+    """Drop the world's flat programs of ``ops`` at ``root`` and return
+    their keys: a launch that needs one again must rebuild it."""
+    keys = {k for k in list(coll._cache)
+            if k[0] == "flat" and k[1] in ops and k[5] == root}
+    for k in keys:
+        coll._cache.pop(k, None)
+    return keys
+
+
 def test_rooted_collectives_use_2d_tree(world):
-    """At W=8 the context folds the mesh to (2, 4) and routes rooted ops
-    (bcast/scatter/gather under AUTO; bcast also accepts the explicit TREE
-    selector) through the hierarchical Tree2DCollectives — correct results
-    AND the tree program cache proves the routing."""
+    """Rooted ops on host mirrors run the flat programs their
+    device-resident twins run — no 2D tree, even where W=8 folds to
+    (2, 4): the world's program cache gains the ``"flat"`` program of
+    each of bcast/scatter/gather/reduce, and the results are right."""
     ctx = world[0].device.ctx
-    assert ctx.tree is not None and (ctx.tree.O, ctx.tree.I) == (2, 4)
-    ctx.tree._cache.clear()
+    coll = ctx.coll
+    assert not hasattr(ctx, "tree")
     count, root = 12, 5
+    rooted = {"bcast", "scatter", "gather", "reduce"}
+    _pop_flat(coll, rooted, root)
     x = _data(count, np.float32, 99)
     chunks = _data(W * count, np.float32, 98)
     ins = [_data(count, np.float32, 90 + r) for r in range(W)]
@@ -436,13 +449,10 @@ def test_rooted_collectives_use_2d_tree(world):
                                    chunks[r * count:(r + 1) * count])
     np.testing.assert_allclose(res[root][2], np.concatenate(ins))
     np.testing.assert_allclose(res[root][3], sum(ins), rtol=1e-5)
-    assert {op for (op, *_rest) in ctx.tree._cache} == {
-        "bcast", "scatter", "gather", "reduce"}
+    assert {k[1] for k in _pop_flat(coll, rooted, root)} == rooted
 
-    # ETH-compressed reduce must stay OFF the tree: the 1-D path's
+    # an ETH-compressed reduce runs the flat program of its wire: the
     # decompress-before-arith wire numerics are the contract
-    ctx.tree._cache.clear()
-
     def fc(a):
         src = a.buffer(data=ins[a.rank])
         dst = a.buffer((count,), np.float32) if a.rank == root else None
@@ -451,7 +461,98 @@ def test_rooted_collectives_use_2d_tree(world):
 
     out = run_ranks(world, fc)[root]
     np.testing.assert_allclose(out, sum(ins), atol=0.05)
-    assert not ctx.tree._cache
+    assert {k[4] for k in _pop_flat(coll, {"reduce"}, root)} == {"float16"}
+
+
+@pytest.mark.parametrize("op", ["bcast", "scatter", "gather", "reduce"])
+def test_rooted_op_same_bits_on_every_placement(world, op):
+    """On the 8-rank world (a 2x4 factorization, where a 2D tree once
+    served host mirrors) a rooted op leaves the same bits whether every
+    buffer is device-resident, every buffer a host mirror, or the group
+    mixes the two: all three run one flat program."""
+    import jax
+
+    count, root = 12, 3
+    ins = [_data(W * count, np.float32, 700 + r) for r in range(W)]
+
+    def run(on_device):
+        def fn(a):
+            resident = on_device(a.rank)
+            x = ins[a.rank]
+
+            def buf(n, data=None):
+                if data is None:
+                    return a.buffer((n,), np.float32,
+                                    device_resident=resident)
+                if resident:
+                    data = jax.device_put(data, a.device.my_device)
+                return a.buffer(data=data)
+
+            mine = a.rank == root
+            if op == "bcast":
+                out = buf(count, x[:count])
+                a.bcast(out, count, root=root)
+            elif op == "scatter":
+                out = buf(count)
+                a.scatter(buf(W * count, x) if mine else None, out, count,
+                          root=root)
+            elif op == "gather":
+                out = buf(W * count) if mine else None
+                a.gather(buf(count, x[:count]), out, count, root=root)
+            else:
+                out = buf(count) if mine else None
+                a.reduce(buf(count, x[:count]), out, count, root=root)
+            return None if out is None else out.data.copy()
+
+        return run_ranks(world, fn)
+
+    device = run(lambda r: True)
+    host = run(lambda r: False)
+    mixed = run(lambda r: r % 2 == 0)
+    for r in range(W):
+        if device[r] is None:
+            assert host[r] is None and mixed[r] is None
+            continue
+        assert device[r].tobytes() == host[r].tobytes(), r
+        assert device[r].tobytes() == mixed[r].tobytes(), r
+    rows = [x[:count] for x in ins]
+    want = {"bcast": [rows[root]] * W,
+            "scatter": [ins[root][r * count:(r + 1) * count]
+                        for r in range(W)],
+            "gather": {root: np.concatenate(rows)},
+            "reduce": {root: sum(rows)}}[op]
+    for r, w in (enumerate(want) if isinstance(want, list)
+                 else want.items()):
+        np.testing.assert_allclose(device[r], w, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("op", ["allreduce", "reduce"])
+def test_64bit_collective_refused_not_truncated(world, op):
+    """With x64 off jax holds int64 in 32 bits, so an int64 collective on
+    host mirrors fails with INVALID_CALL before any payload reaches a
+    device, and leaves its destinations as they were (it used to return
+    a truncated sum)."""
+    import jax
+
+    if jax.config.jax_enable_x64:
+        pytest.skip("x64 enabled: int64 is canonical")
+    root = 0
+
+    def fn(a):
+        src = a.buffer(data=np.full(4, 2**40 + a.rank, np.int64))
+        dst = (a.buffer(data=np.full(4, -7, np.int64))
+               if op == "allreduce" or a.rank == root else None)
+        with pytest.raises(ACCLError) as ei:
+            if op == "allreduce":
+                a.allreduce(src, dst, 4)
+            else:
+                a.reduce(src, dst, 4, root=root)
+        assert ErrorCode.INVALID_CALL in ei.value.errors
+        return None if dst is None else dst.data.copy()
+
+    for out in run_ranks(world, fn):
+        if out is not None:
+            np.testing.assert_array_equal(out, np.full(4, -7, np.int64))
 
 
 def test_wire_compressed_rooted_ops_match_emulator_tier(world):
@@ -506,10 +607,12 @@ def test_wire_compressed_rooted_ops_match_emulator_tier(world):
 
 
 def test_bcast_round_robin_selector_skips_tree(world):
-    """An explicit ROUND_ROBIN selector pins the 1-D masked lowering even
-    when a tree context exists (algorithm parity with the move engine)."""
+    """An explicit ROUND_ROBIN selector on host mirrors runs the flat
+    binomial bcast, the program AUTO and the device-resident twin run
+    (algorithm parity with the move engine): no tree exists to skip."""
     ctx = world[0].device.ctx
-    ctx.tree._cache.clear()
+    assert not hasattr(ctx, "tree")
+    _pop_flat(ctx.coll, {"bcast"}, 0)
     x = _data(6, np.float32, 77)
 
     def fn(a):
@@ -519,7 +622,8 @@ def test_bcast_round_robin_selector_skips_tree(world):
 
     for out in run_ranks(world, fn):
         np.testing.assert_allclose(out, x)
-    assert not ctx.tree._cache
+    assert {k[:3] for k in _pop_flat(ctx.coll, {"bcast"}, 0)} == {
+        ("flat", "bcast", "xla")}
 
 
 def test_tpu_world_real_chip():
