@@ -55,6 +55,26 @@ def _phases_strip_flat(compress_phases: str | None) -> bool:
         f"{compress_phases!r}")
 
 
+# The most call plans one driver keeps; the oldest goes first. A plan is
+# one call signature, not one buffer, so a job holds a handful.
+_MAX_CALL_PLANS = 256
+
+
+class _CallPlan:
+    """The descriptor fields ``ACCL._prepare`` resolved for one call
+    signature. ``tuned``: a tuner would answer the signature otherwise
+    (an AUTO algorithm of a tunable op, or the tuner's block size)."""
+
+    __slots__ = ("arithcfg", "compression", "algorithm", "tuned")
+
+    def __init__(self, arithcfg, compression: Compression,
+                 algorithm: CollectiveAlgorithm, tuned: bool):
+        self.arithcfg = arithcfg
+        self.compression = compression
+        self.algorithm = algorithm
+        self.tuned = tuned
+
+
 class ACCL:
     """One rank's handle to the collective engine.
 
@@ -110,6 +130,10 @@ class ACCL:
             # trace encodings — reject unsafe charsets at the API edge
         self.tenant = tenant
         self._arith_memo: dict[frozenset, object] = {}
+        # call plans (_prepare): signature -> _CallPlan, oldest first;
+        # counted driver-local like _call_counts below
+        self._plans: dict[tuple, _CallPlan] = {}
+        self._plan_counts = {"hit": 0, "miss": 0, "bypass": 0}
         self.arith_registry = (arith_registry if arith_registry is not None
                                else dict(DEFAULT_ARITH_CONFIGS))
         self.communicators: list[Communicator] = []
@@ -220,12 +244,14 @@ class ACCL:
     @arith_registry.setter
     def arith_registry(self, registry: dict):
         self._arith_registry = registry
-        self._arith_memo.clear()
+        self.invalidate_arith_cache()
 
     def invalidate_arith_cache(self):
-        """Drop memoized arith-config resolutions (call after mutating
-        ``arith_registry`` in place)."""
+        """Drop memoized arith-config resolutions, and the call plans
+        built from them (call after mutating ``arith_registry`` in
+        place)."""
         self._arith_memo.clear()
+        self._plans.clear()
 
     @property
     def comm(self) -> Communicator:
@@ -743,6 +769,9 @@ class ACCL:
             yield ("counter", "accl_bytes_total",
                    dict(labels, op=op, comm_id=comm_id,
                         tenant=self.tenant or f"comm-{comm_id}"), n)
+        for result, n in list(self._plan_counts.items()):
+            yield ("counter", "accl_call_plan_total",
+                   dict(labels, result=result), n)
 
     def deinit(self):
         # withdraw THIS driver's windows only — on a shared device
@@ -846,6 +875,15 @@ class ACCL:
         ``compress_dtype``) upgrades the wire from plain narrowing to
         block-scaled quantization (accl_tpu/quant.py): True = tuner-
         recommended block size, an int = explicit block.
+
+        The first call of a signature (the op, communicator, operand,
+        result and stream dtypes, and the wire and algorithm asked for)
+        keeps what it resolved as a call plan; later calls of it build
+        their descriptor from the plan, counted in
+        ``accl_call_plan_total``. A signature a tuner answers is resolved
+        afresh on every call while a tuner is attached (a bypass). The
+        key holds ``type(block_scale)``: ``True`` (the default block) and
+        ``1`` (a block of one) are equal as keys.
         """
         if getattr(comm, "revoked", False):
             # ULFM-style containment: a revoked communicator accepts no
@@ -854,6 +892,22 @@ class ACCL:
             raise ACCLError(int(ErrorCode.PEER_FAILED),
                             f"{scenario.name} on revoked communicator "
                             f"{comm.comm_id}")
+        key = (scenario, comm.comm_id,
+               op0.dtype if op0 is not None else None,
+               op1.dtype if op1 is not None else None,
+               res.dtype if res is not None else None,
+               stream_dtype, compress_dtype, block_scale, type(block_scale),
+               algorithm)
+        plan = self._plans.get(key)
+        if plan is not None and not (plan.tuned and self.tuner is not None):
+            self._plan_counts["hit"] += 1
+            return CallDescriptor(
+                scenario, count, comm.comm_id, root_src_dst, func, tag,
+                plan.arithcfg, plan.compression, stream_flags,
+                plan.algorithm,
+                op0.address if op0 is not None else 0,
+                op1.address if op1 is not None else 0,
+                res.address if res is not None else 0)
         dtypes = {b.dtype for b in (op0, op1, res) if b is not None}
         compression = Compression.NONE
         if stream_dtype is not None:
@@ -917,6 +971,9 @@ class ACCL:
         if isinstance(algorithm, str):
             algorithm = CollectiveAlgorithm[algorithm.upper()]
         algorithm = CollectiveAlgorithm(algorithm)
+        tuned = block_scale is True or (
+            algorithm == CollectiveAlgorithm.AUTO
+            and scenario.name in VALID_ALGORITHMS)
         if (algorithm == CollectiveAlgorithm.AUTO and self.tuner is not None
                 and scenario.name in VALID_ALGORITHMS):
             # resolve AUTO here so the concrete choice crosses the wire to
@@ -936,11 +993,19 @@ class ACCL:
                     # a flat algorithm (accl_tpu/hier lowers
                     # HIERARCHICAL before a descriptor exists)
                     algorithm = DEFAULT_ALGORITHMS[scenario.name]
+        algorithm = CollectiveAlgorithm(algorithm)
+        if tuned and self.tuner is not None:
+            self._plan_counts["bypass"] += 1
+        else:
+            self._plan_counts["miss"] += 1
+            if len(self._plans) >= _MAX_CALL_PLANS:
+                del self._plans[next(iter(self._plans))]
+            self._plans[key] = _CallPlan(cfg, compression, algorithm, tuned)
         return CallDescriptor(
             scenario=scenario, count=count, comm_id=comm.comm_id,
             root_src_dst=root_src_dst, function=func, tag=tag,
             arithcfg=cfg, compression=compression, stream_flags=stream_flags,
-            algorithm=CollectiveAlgorithm(algorithm),
+            algorithm=algorithm,
             addr_0=op0.address if op0 is not None else 0,
             addr_1=op1.address if op1 is not None else 0,
             addr_2=res.address if res is not None else 0)
